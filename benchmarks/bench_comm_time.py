@@ -154,6 +154,7 @@ def _measured_worker(out_dir: str, steps: int, cb: float) -> dict:
     from repro.data.pipeline import DecentralizedBatches
     from repro.dist import decen_train as dt
     from repro.dist import sharding as shd
+    from repro.launch.mesh import make_mesh
     from repro.models.transformer import Model
     from repro.optim.optimizers import sgd
     from repro.telemetry import StepTimer, TraceRecorder
@@ -163,7 +164,7 @@ def _measured_worker(out_dir: str, steps: int, cb: float) -> dict:
     g = paper_figure1_graph()
     cfg = get_smoke_config("internlm2_1_8b")
     model = Model(cfg)
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     spec = dt.make_spec(mesh, cfg, multi_pod=False)
     plan = plan_matcha(g, cb, budget_steps=800)
     sched = plan.schedule(steps + warmup, seed=1)

@@ -65,13 +65,14 @@ def _worker(out_dir: str, steps: int):
     from repro.data.pipeline import DecentralizedBatches
     from repro.dist import decen_train as dt
     from repro.dist import sharding as shd
+    from repro.launch.mesh import make_mesh
     from repro.models.transformer import Model
     from repro.optim.optimizers import sgd
 
     g = paper_figure1_graph()
     cfg = get_smoke_config("internlm2_1_8b")
     model = Model(cfg)
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     spec = dt.make_spec(mesh, cfg, multi_pod=False)
 
     runs = [("vanilla", None), ("matcha", 0.5), ("matcha", 0.25),

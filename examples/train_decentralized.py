@@ -47,6 +47,7 @@ from repro.data.pipeline import DecentralizedBatches
 from repro.dist import decen_train as dt
 from repro.dist import fsdp
 from repro.dist import sharding as shd
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import Model
 from repro.optim.optimizers import sgd
 from repro.checkpoint import ckpt as ckpt_lib
@@ -90,9 +91,9 @@ if args.shard > 1:
     if batch_per_node % args.shard:
         raise SystemExit(f"batch_per_node {batch_per_node} must divide by "
                          f"--shard {args.shard}")
-    mesh = jax.make_mesh((8, args.shard, 1), ("data", "shard", "model"))
+    mesh = make_mesh((8, args.shard, 1), ("data", "shard", "model"))
 else:
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
 spec = dt.make_spec(mesh, cfg, multi_pod=False)
 opt = sgd(0.15 if args.scale == "tiny" else 0.05, momentum=0.9)
 layout = None
@@ -174,7 +175,7 @@ with jax.set_mesh(mesh):
             params = fsdp.make_fsdp_gossip_flush(plan, spec, layout)(
                 params, gstate)
         else:
-            params = dt.make_gossip_flush(plan, spec, bplan)(params, gstate)
+            params = dt.make_gossip_flush(model, plan, spec, bplan)(params, gstate)
         print(f"flushed in-flight gossip: consensus "
               f"{float(consensus(params)):.2e}")
 

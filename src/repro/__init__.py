@@ -1,47 +1,1 @@
-"""repro: MATCHA decentralized-SGD reproduction on jax.
-
-Importing this package installs two tiny forward-compat shims for the
-jax version pinned in the container (0.4.x), so that runtime code and
-tests can be written against the modern public API:
-
-  * ``jax.shard_map(f, mesh=..., in_specs=..., out_specs=..., axis_names=...)``
-    -> ``jax.experimental.shard_map.shard_map`` with the non-listed mesh
-    axes left *auto* (GSPMD-visible). ``check_rep`` is forced off: the
-    gossip bodies use ``ppermute`` with data-dependent pairs, which the
-    replication checker cannot reason about.
-  * ``jax.set_mesh(mesh)`` -> a context manager entering the mesh's
-    resource env (what newer jax does for bare-PartitionSpec
-    ``with_sharding_constraint`` resolution).
-
-Both shims are no-ops on jax versions that already expose the names.
-"""
-from __future__ import annotations
-
-import contextlib
-
-import jax
-
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                          check_rep=False, **kwargs):
-        del check_rep, kwargs
-        auto = frozenset()
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False, auto=auto,
-        )
-
-    jax.shard_map = _compat_shard_map
-
-if not hasattr(jax, "set_mesh"):
-
-    @contextlib.contextmanager
-    def _compat_set_mesh(mesh):
-        with mesh:
-            yield mesh
-
-    jax.set_mesh = _compat_set_mesh
+"""repro: MATCHA decentralized-SGD reproduction on jax."""

@@ -172,7 +172,7 @@ def main(argv=None) -> int:
     from repro.dist import fsdp
     from repro.dist import serve as sv
     from repro.dist import sharding as shd
-    from repro.launch.mesh import make_test_mesh
+    from repro.launch.mesh import make_mesh, make_test_mesh
     from repro.models.transformer import Model
     from repro.optim.optimizers import sgd
 
@@ -499,7 +499,7 @@ def main(argv=None) -> int:
 
     # -- serve steps: dtype lint (GSPMD-partitioned, no shard_map) -----------
     _log("serve steps: prefill/decode dtype lint")
-    mesh_s = jax.make_mesh((1, 1), ("data", "model"))
+    mesh_s = make_mesh((1, 1), ("data", "model"))
     rules = shd.serve_rules(mesh_s, cfg)
     max_len = args.seq + 16
     caches = sv.abstract_caches(model, B, max_len)

@@ -38,6 +38,7 @@ PARSER_FACTORIES = {
     "benchmarks.run": "build_parser",
     "benchmarks.bench_comm_time": "build_parser",
     "benchmarks.bench_convergence": "build_parser",
+    "chip_smoke": "build_parser",
 }
 
 # Markdown files the lint walks (repo-root relative).

@@ -54,6 +54,7 @@ from typing import Sequence
 
 import jax
 import numpy as np
+from jax.extend.core import Literal
 
 from repro.analysis.checks import Violation
 from repro.analysis.traversal import iter_eqns, to_closed_jaxpr
@@ -116,8 +117,11 @@ def find_pallas_calls(closed) -> list:
 # ---------------------------------------------------------------------------
 def _block_dims(bm) -> tuple:
     """(array_shape, block_shape, dtype) of one BlockMapping."""
-    sds = bm.array_shape_dtype
-    return tuple(sds.shape), tuple(int(b) for b in bm.block_shape), sds.dtype
+    aval = bm.array_aval
+    # block dims are ``pl.Blocked``/``pl.Element`` (carrying
+    # ``block_size``) or ``pl.Squeezed`` (size 1)
+    block = tuple(int(getattr(b, "block_size", 1)) for b in bm.block_shape)
+    return tuple(aval.shape), block, aval.dtype
 
 
 def check_contract_shape(info: KernelCallInfo, contract: dict, where: str):
@@ -169,7 +173,7 @@ def _index_map_fn(bm, grid_len: int):
         used = set()
         for eqn, _ctx in iter_eqns(imj):
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     used.add(v)
         if any(v in used for v in extra):
             return None
@@ -297,11 +301,11 @@ def _used_vars(info: KernelCallInfo) -> set:
     used = set()
     for eqn, _ctx in _body_eqns(info):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, Literal):
                 used.add(v)
     for jx in _all_jaxprs(info.body):
         for v in jx.outvars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, Literal):
                 used.add(v)
     return used
 
@@ -324,7 +328,7 @@ def _literal_comparisons(info: KernelCallInfo):
         if str(eqn.primitive) not in _CMP_PRIMS:
             continue
         for v in eqn.invars:
-            if isinstance(v, jax.core.Literal) and np.ndim(v.val) == 0:
+            if isinstance(v, Literal) and np.ndim(v.val) == 0:
                 try:
                     yield eqn, int(v.val)
                 except (TypeError, ValueError):

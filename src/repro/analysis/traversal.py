@@ -19,6 +19,7 @@ from typing import Any, Callable, Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 __all__ = [
     "EqnContext",
@@ -30,7 +31,7 @@ __all__ = [
 ]
 
 
-def sub_jaxprs(params: dict) -> Iterator[jax.core.Jaxpr]:
+def sub_jaxprs(params: dict) -> Iterator[Jaxpr]:
     """Yield every Jaxpr reachable from one equation's params.
 
     Sub-jaxprs appear as ``Jaxpr`` or ``ClosedJaxpr`` param values, either
@@ -40,23 +41,23 @@ def sub_jaxprs(params: dict) -> Iterator[jax.core.Jaxpr]:
     for v in params.values():
         vs = v if isinstance(v, (list, tuple)) else (v,)
         for w in vs:
-            if isinstance(w, jax.core.ClosedJaxpr):
+            if isinstance(w, ClosedJaxpr):
                 yield w.jaxpr
-            elif isinstance(w, jax.core.Jaxpr):
+            elif isinstance(w, Jaxpr):
                 yield w
 
 
-def to_closed_jaxpr(obj: Any, *args: Any) -> jax.core.ClosedJaxpr:
+def to_closed_jaxpr(obj: Any, *args: Any) -> ClosedJaxpr:
     """Normalize to a ``ClosedJaxpr``.
 
     Accepts a ``ClosedJaxpr``, a bare ``Jaxpr`` (wrapped with no consts),
     or a callable — in which case ``*args`` are example arguments and the
     callable is traced with ``jax.make_jaxpr``.
     """
-    if isinstance(obj, jax.core.ClosedJaxpr):
+    if isinstance(obj, ClosedJaxpr):
         return obj
-    if isinstance(obj, jax.core.Jaxpr):
-        return jax.core.ClosedJaxpr(obj, ())
+    if isinstance(obj, Jaxpr):
+        return ClosedJaxpr(obj, ())
     if callable(obj):
         return jax.make_jaxpr(obj)(*args)
     raise TypeError(f"cannot interpret {type(obj).__name__} as a jaxpr")
@@ -92,7 +93,7 @@ def iter_eqns(jaxpr, ctx: EqnContext | None = None):
     sub-jaxprs are visited with ``in_manual`` set if the equation is a
     ``shard_map`` and ``scan_trips`` multiplied by a scan's ``length``.
     """
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     if ctx is None:
         ctx = EqnContext()
@@ -153,9 +154,12 @@ def source_frames(eqn) -> tuple:
     try:
         from jax._src import source_info_util
 
+        # function_name is the qualified name on Python >= 3.11
+        # ("outer.<locals>.leaf"); the frames carry the bare one
         return tuple(
-            (str(fr.file_name), str(fr.function_name), int(fr.start_line))
-            for fr in source_info_util.user_frames(si)
+            (str(fr.file_name), str(fr.function_name).rsplit(".", 1)[-1],
+             int(fr.start_line))
+            for fr in source_info_util.user_frames(si.traceback)
         )
     except Exception:
         return ()

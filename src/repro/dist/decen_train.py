@@ -32,18 +32,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-import repro  # ensures the jax.shard_map compat shim is installed  # noqa: F401
 from repro.configs.base import ModelConfig
 from repro.dist import bucketing
 from repro.dist import sharding as shd
 from repro.dist.gossip import (
     NodeAxisInfo,
+    apply_gossip,
     delayed_delta,
     launch_matchings_masked,
     mix_matchings,
     mix_matchings_masked,
 )
-from repro.kernels import ops
 from repro.optim.optimizers import Optimizer, apply_updates, clip_by_global_norm
 
 PyTree = Any
@@ -72,7 +71,8 @@ class DistSpec:
 
     @property
     def node_info(self) -> NodeAxisInfo:
-        return NodeAxisInfo(axis_names=self.node_axes, num_nodes=self.num_nodes)
+        return NodeAxisInfo(axis_names=self.node_axes,
+                            num_nodes=self.num_nodes, mesh=self.mesh)
 
 
 def make_spec(
@@ -128,11 +128,19 @@ def init_stacked_opt_state(opt: Optimizer, model, spec: DistSpec) -> PyTree:
     return _stack(opt.init(zeros_local), spec.num_nodes)
 
 
+def local_param_pspecs(model, spec: DistSpec) -> PyTree:
+    """Per-parameter PartitionSpecs of one node's tree: its dims per
+    the model's logical axes (tensor-parallel where the rules map
+    them). Inside the node-manual step these are the leaves' specs over
+    the axes left to the compiler."""
+    return shd.param_pspecs(model.logical_axes(), spec.rules)
+
+
 def stacked_param_shardings(model, spec: DistSpec) -> PyTree:
     """Per-parameter PartitionSpecs for the stacked tree: the leading
-    node dim over the node axes, the per-node dims per the model's
-    logical axes (tensor-parallel where the rules map them)."""
-    base = shd.param_pspecs(model.logical_axes(), spec.rules)
+    node dim over the node axes, the per-node dims per
+    :func:`local_param_pspecs`."""
+    base = local_param_pspecs(model, spec)
     nodes = spec.nodes_axis
     return jax.tree.map(
         lambda s: P(nodes, *s), base, is_leaf=lambda v: isinstance(v, P)
@@ -247,6 +255,8 @@ def _apply_delayed(
     delta_buckets: Tuple[jax.Array, ...],
     bplan: bucketing.BucketPlan,
     alpha: float,
+    spec: DistSpec,
+    leaf_specs: PyTree,
 ) -> PyTree:
     """Land an in-flight delayed correction on a per-node param tree:
     ``x <- x + alpha * delta`` through the fused gossip-axpy (the one
@@ -257,7 +267,8 @@ def _apply_delayed(
         lambda x, d: x if d is None else x.astype(jnp.float32) + d,
         p, delta_tree,
     )
-    return ops.gossip_apply(p, target, alpha)
+    return apply_gossip(p, target, alpha, mesh=spec.mesh,
+                        leaf_specs=leaf_specs)
 
 
 def _reject_shard_mesh(spec: DistSpec, what: str) -> None:
@@ -271,7 +282,9 @@ def _reject_shard_mesh(spec: DistSpec, what: str) -> None:
         )
 
 
-def make_gossip_flush(plan, spec: DistSpec, bplan: bucketing.BucketPlan):
+def make_gossip_flush(
+    model, plan, spec: DistSpec, bplan: bucketing.BucketPlan
+):
     """Land the exchange still in flight after the last overlap step:
 
         params = flush(params, gstate)
@@ -282,10 +295,13 @@ def make_gossip_flush(plan, spec: DistSpec, bplan: bucketing.BucketPlan):
     _reject_shard_mesh(spec, "make_gossip_flush")
     nodes_ax = spec.nodes_axis
     alpha = float(plan.alpha)
+    leaf_specs = local_param_pspecs(model, spec)
 
     def body(params, gstate):
         p = jax.tree.map(lambda a: a[0], params)
-        p = _apply_delayed(p, tuple(a[0] for a in gstate.delta), bplan, alpha)
+        p = _apply_delayed(
+            p, tuple(a[0] for a in gstate.delta), bplan, alpha, spec,
+            leaf_specs)
         return jax.tree.map(lambda a: a[None], p)
 
     stepped = jax.shard_map(
@@ -294,6 +310,7 @@ def make_gossip_flush(plan, spec: DistSpec, bplan: bucketing.BucketPlan):
         in_specs=(P(nodes_ax), gossip_state_pspecs(spec, bplan)),
         out_specs=P(nodes_ax),
         axis_names=set(spec.node_axes),
+        check_vma=False,
     )
     return jax.jit(stepped)
 
@@ -330,6 +347,10 @@ def make_train_step(
     (nodes, per_node_batch, ...); ``bits`` is the (M,) float activation
     row of the a-priori schedule (ignored for "static"/"none").
     ``losses``/``metrics`` come back per node, shape (nodes,).
+    The step donates ``params``, ``opt_state`` (and ``gstate``): the
+    caller must not read the arrays it passed in after the call.
+    ``plan`` may be ``None`` for ``gossip_mode="none"``, which has no
+    topology (a one-node run).
 
     ``faulted=True`` builds the link-failure-tolerant variant: ``bits``
     becomes the ``(nodes, M)`` *per-node effective* activation array
@@ -356,11 +377,15 @@ def make_train_step(
     if gossip_mode not in ("masked", "static", "overlap", "none"):
         raise ValueError(f"unknown gossip_mode {gossip_mode!r}")
     _reject_shard_mesh(spec, "make_train_step")
+    if plan is None and gossip_mode != "none":
+        raise ValueError(f"gossip_mode {gossip_mode!r} needs a plan")
     info = spec.node_info
+    leaf_specs = local_param_pspecs(model, spec)
     nodes_ax = spec.nodes_axis
     mesh = spec.mesh
-    perms = np.asarray(plan.permutations)
-    alpha = float(plan.alpha)
+    if plan is not None:
+        perms = np.asarray(plan.permutations)
+        alpha = float(plan.alpha)
     active = tuple(int(j) for j in active)
     if gossip_mode == "overlap":
         bplan = bucket_plan or param_bucket_plan(model)
@@ -389,11 +414,13 @@ def make_train_step(
         p, s, loss, metrics = sgd_half(p, s, batch)
         with jax.named_scope("gossip"):
             if gossip_mode == "masked":
-                p = mix_matchings_masked(p, alpha, perms, bits, info)
+                p = mix_matchings_masked(
+                    p, alpha, perms, bits, info, leaf_specs=leaf_specs)
             elif gossip_mode == "static":
                 p = mix_matchings(
                     p, alpha, perms, active, info,
                     gate_bits=bits if faulted else None,
+                    leaf_specs=leaf_specs,
                 )
         return expand(p), expand(s), loss[None], expand(metrics)
 
@@ -405,7 +432,8 @@ def make_train_step(
         # 1. land the delayed correction from the in-flight exchange
         with jax.named_scope("gossip_apply"):
             p = _apply_delayed(
-                p, tuple(a[0] for a in gstate.delta), bplan, alpha
+                p, tuple(a[0] for a in gstate.delta), bplan, alpha, spec,
+                leaf_specs,
             )
         # 2. launch this iteration's exchange on the corrected params;
         #    the grads below don't consume it, so the collectives (and
@@ -435,8 +463,9 @@ def make_train_step(
                 P(nodes_ax), P(nodes_ax), gspecs, P(nodes_ax), P(nodes_ax),
             ),
             axis_names=set(spec.node_axes),
+            check_vma=False,
         )
-        return jax.jit(stepped)
+        return jax.jit(stepped, donate_argnums=(0, 1, 2))
 
     stepped = jax.shard_map(
         body,
@@ -444,8 +473,9 @@ def make_train_step(
         in_specs=(P(nodes_ax), P(nodes_ax), P(nodes_ax), bits_spec),
         out_specs=(P(nodes_ax), P(nodes_ax), P(nodes_ax), P(nodes_ax)),
         axis_names=set(spec.node_axes),
+        check_vma=False,
     )
-    return jax.jit(stepped)
+    return jax.jit(stepped, donate_argnums=(0, 1))
 
 
 # ---------------------------------------------------------------------------
@@ -500,12 +530,16 @@ def make_phased_train_step(
             "serialize the overlap being measured)"
         )
     _reject_shard_mesh(spec, "make_phased_train_step")
+    if plan is None and gossip_mode != "none":
+        raise ValueError(f"gossip_mode {gossip_mode!r} needs a plan")
     timer = timer or StepTimer()
     info = spec.node_info
+    leaf_specs = local_param_pspecs(model, spec)
     nodes_ax = spec.nodes_axis
     mesh = spec.mesh
-    perms = np.asarray(plan.permutations)
-    alpha = float(plan.alpha)
+    if plan is not None:
+        perms = np.asarray(plan.permutations)
+        alpha = float(plan.alpha)
     active = tuple(int(j) for j in active)
     expand = lambda t: jax.tree.map(lambda a: a[None], t)
     manual = set(spec.node_axes)
@@ -532,11 +566,13 @@ def make_phased_train_step(
         if faulted:
             bits = bits[0]            # (nodes, M) -> this node's (M,) row
         if gossip_mode == "masked":
-            p = mix_matchings_masked(p, alpha, perms, bits, info)
+            p = mix_matchings_masked(
+                p, alpha, perms, bits, info, leaf_specs=leaf_specs)
         else:
             p = mix_matchings(
                 p, alpha, perms, active, info,
                 gate_bits=bits if faulted else None,
+                leaf_specs=leaf_specs,
             )
         return expand(p)
 
@@ -545,12 +581,14 @@ def make_phased_train_step(
         in_specs=(P(nodes_ax), P(nodes_ax)),
         out_specs=(P(nodes_ax), P(nodes_ax), P(nodes_ax)),
         axis_names=manual,
+        check_vma=False,
     ))
     optimizer = jax.jit(jax.shard_map(
         opt_body, mesh=mesh,
         in_specs=(P(nodes_ax), P(nodes_ax), P(nodes_ax)),
         out_specs=(P(nodes_ax), P(nodes_ax)),
         axis_names=manual,
+        check_vma=False,
     ))
     gossip = None
     if gossip_mode != "none":
@@ -559,6 +597,7 @@ def make_phased_train_step(
             in_specs=(P(nodes_ax), P(nodes_ax) if faulted else P()),
             out_specs=P(nodes_ax),
             axis_names=manual,
+            check_vma=False,
         ))
 
     def step(params, opt_state, batch, bits, *, step: int = -1):

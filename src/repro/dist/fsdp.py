@@ -86,15 +86,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-import repro  # ensures the jax.shard_map compat shim is installed  # noqa: F401
 from repro.dist import bucketing
 from repro.dist.decen_train import DistSpec, GossipState
 from repro.dist.gossip import (
+    apply_gossip,
     delayed_delta,
     launch_matchings_masked,
     mix_matchings_masked,
 )
-from repro.kernels import ops
 from repro.optim.optimizers import Optimizer, apply_updates
 
 PyTree = Any
@@ -795,7 +794,9 @@ def make_fsdp_train_step(
     ``(nodes, batch_per_node, ...)`` with ``batch_per_node % S == 0``
     (split over the shard axis in-step); ``bits`` the (M,) activation
     row. ``losses``/``metrics`` come back ``(nodes, S)`` with identical
-    columns (pmean'd over the shard axis).
+    columns (pmean'd over the shard axis). The step donates ``shards``,
+    ``opt_state`` (and ``gstate``): the caller must not read the arrays
+    it passed in after the call.
 
     ``faulted=True`` is the link-failure-tolerant variant (mirroring
     ``decen_train.make_train_step``): ``bits`` becomes the per-node
@@ -892,7 +893,7 @@ def make_fsdp_train_step(
         # 1. land the delayed correction from the in-flight exchange
         delta = tuple(a[0, 0] for a in gstate.delta)
         target = tuple(x + d for x, d in zip(ps, delta))
-        ps = ops.gossip_apply(ps, target, alpha)
+        ps = apply_gossip(ps, target, alpha, mesh=spec.mesh)
         # 2. launch this iteration's exchange on the corrected shards;
         #    nothing below consumes it, so the ppermutes overlap the
         #    all-gather + fwd/bwd
@@ -919,8 +920,9 @@ def make_fsdp_train_step(
             in_specs=(pspec, opt_spec, gspecs, batch_spec, bits_spec),
             out_specs=(pspec, opt_spec, gspecs, ls_spec, ls_spec),
             axis_names=manual,
+            check_vma=False,
         )
-        return jax.jit(stepped)
+        return jax.jit(stepped, donate_argnums=(0, 1, 2))
 
     stepped = jax.shard_map(
         body,
@@ -928,8 +930,9 @@ def make_fsdp_train_step(
         in_specs=(pspec, opt_spec, batch_spec, bits_spec),
         out_specs=(pspec, opt_spec, ls_spec, ls_spec),
         axis_names=manual,
+        check_vma=False,
     )
-    return jax.jit(stepped)
+    return jax.jit(stepped, donate_argnums=(0, 1))
 
 
 def make_phased_fsdp_train_step(
@@ -1038,12 +1041,14 @@ def make_phased_fsdp_train_step(
         in_specs=(pspec, batch_spec),
         out_specs=(pspec, ls_spec, ls_spec),
         axis_names=manual,
+        check_vma=False,
     ))
     optimizer = jax.jit(jax.shard_map(
         opt_body, mesh=mesh,
         in_specs=(pspec, opt_spec, pspec),
         out_specs=(pspec, opt_spec),
         axis_names=manual,
+        check_vma=False,
     ))
     gossip = None
     if gossip_mode != "none":
@@ -1052,6 +1057,7 @@ def make_phased_fsdp_train_step(
             in_specs=(pspec, P(nodes_ax) if faulted else P()),
             out_specs=pspec,
             axis_names=manual,
+            check_vma=False,
         ))
 
     def step(shards, opt_state, batch, bits, *, step: int = -1):
@@ -1090,7 +1096,7 @@ def make_fsdp_gossip_flush(plan, spec: DistSpec, layout: AnyFsdpLayout):
         ps = tuple(a[0, 0] for a in shards)
         delta = tuple(a[0, 0] for a in gstate.delta)
         target = tuple(x + d for x, d in zip(ps, delta))
-        out = ops.gossip_apply(ps, target, alpha)
+        out = apply_gossip(ps, target, alpha, mesh=spec.mesh)
         return tuple(a[None, None] for a in out)
 
     pspec = tuple(P(nodes_ax, "shard") for _ in range(layout.plan.num_buckets))
@@ -1100,5 +1106,6 @@ def make_fsdp_gossip_flush(plan, spec: DistSpec, layout: AnyFsdpLayout):
         in_specs=(pspec, fsdp_gossip_state_pspecs(spec, layout)),
         out_specs=pspec,
         axis_names=manual,
+        check_vma=False,
     )
     return jax.jit(stepped)

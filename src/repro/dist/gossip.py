@@ -31,11 +31,13 @@ fwd/bwd compute instead of serializing after it.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Sequence, Tuple, Union
+from typing import Any, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels import ops
 
@@ -62,10 +64,12 @@ FP32_UPCAST_SITES = (
 
 @dataclasses.dataclass(frozen=True)
 class NodeAxisInfo:
-    """Which mesh axes the decentralized nodes live on."""
+    """Which mesh axes the decentralized nodes live on, and the mesh
+    (see :func:`apply_gossip`)."""
 
     axis_names: Tuple[str, ...]
     num_nodes: int
+    mesh: Optional[Mesh] = None
 
     @property
     def axis_name(self) -> Union[str, Tuple[str, ...]]:
@@ -117,14 +121,16 @@ def _check_bits(bits, num_matchings: int) -> None:
 # ---------------------------------------------------------------------------
 def mix_dense(stacked: PyTree, W: jax.Array) -> PyTree:
     """Apply a dense mixing matrix to node-stacked leaves: out_i = sum_j
-    W[i, j] x_j (fp32 accumulation). Reference path for tests and for
-    meshes too small to bother with collectives."""
+    W[i, j] x_j (fp32 accumulation, full-precision matmul passes on
+    every backend). Reference path for tests and for meshes too small
+    to bother with collectives."""
 
     def leaf(a):
         if not _is_float(a):
             return a
         out = jnp.einsum(
-            "ij,j...->i...", W.astype(jnp.float32), a.astype(jnp.float32)
+            "ij,j...->i...", W.astype(jnp.float32), a.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
         )
         return out.astype(a.dtype)
 
@@ -134,6 +140,47 @@ def mix_dense(stacked: PyTree, W: jax.Array) -> PyTree:
 # ---------------------------------------------------------------------------
 # shard_map matchings gossip
 # ---------------------------------------------------------------------------
+def apply_gossip(
+    local: PyTree,
+    targets: PyTree,
+    alpha: float,
+    *,
+    mesh: Optional[Mesh] = None,
+    leaf_specs: PyTree = None,
+    impl: str = "auto",
+) -> PyTree:
+    """The consensus update ``x + alpha * (target - x)`` per leaf,
+    through the fused gossip-axpy (``ops.gossip_apply``).
+
+    The node-manual step leaves its other mesh axes (``model``) to the
+    compiler, which cannot partition a Mosaic kernel. The kernel paths
+    therefore run in a nested ``shard_map`` manual over those axes too.
+    ``leaf_specs`` gives each leaf's PartitionSpec over them (the
+    per-node param specs of the sharding rules), so a tensor-parallel
+    leaf is updated shard by shard, with no gather; ``None`` means the
+    leaves are replicated over them.
+
+    ``mesh`` is the run's mesh. Under ``jax.set_mesh`` the lowering of
+    a nested shard_map given only the abstract mesh swaps in the
+    context's mesh, whose axes are all ``Auto``: the enclosing manual
+    axes are lost and Mosaic refuses the kernel. The run's devices with
+    the abstract mesh's axis types keep them. ``None`` (tests off the
+    chip) uses the abstract mesh."""
+    mode = ops.resolve_mode(impl, off_tpu="interpret")
+    update = lambda xs, ys: ops.gossip_apply(xs, ys, alpha, impl=mode)
+    here = jax.sharding.get_abstract_mesh()
+    left = set(here.axis_names) - set(here.manual_axes)
+    if mode == "xla" or not left:
+        return update(local, targets)
+    if mesh is not None:
+        here = Mesh(mesh.devices, mesh.axis_names, axis_types=here.axis_types)
+    specs = P() if leaf_specs is None else leaf_specs
+    return jax.shard_map(
+        update, mesh=here, in_specs=(specs, specs), out_specs=specs,
+        axis_names=left, check_vma=False,
+    )(local, targets)
+
+
 def mix_matchings(
     local: PyTree,
     alpha: float,
@@ -143,6 +190,7 @@ def mix_matchings(
     *,
     impl: str = "auto",
     gate_bits=None,                      # (M,) per-node degradation gates
+    leaf_specs: PyTree = None,           # see apply_gossip
 ) -> PyTree:
     """Static-activation gossip: x + alpha * sum_{j in active} (pi_j(x) - x).
 
@@ -190,7 +238,8 @@ def mix_matchings(
         return xf + delta
 
     targets = jax.tree.map(partner_target, local)
-    return ops.gossip_apply(local, targets, float(alpha), impl=impl)
+    return apply_gossip(local, targets, float(alpha), mesh=info.mesh,
+                        leaf_specs=leaf_specs, impl=impl)
 
 
 def mix_matchings_masked(
@@ -201,6 +250,7 @@ def mix_matchings_masked(
     info: NodeAxisInfo,
     *,
     impl: str = "auto",
+    leaf_specs: PyTree = None,           # see apply_gossip
 ) -> PyTree:
     """Masked gossip: every matching's exchange runs, each delta scaled
     by its (traced) activation bit — one executable for the whole
@@ -234,7 +284,8 @@ def mix_matchings_masked(
         return xf + delta
 
     targets = jax.tree.map(partner_target, local)
-    return ops.gossip_apply(local, targets, float(alpha), impl=impl)
+    return apply_gossip(local, targets, float(alpha), mesh=info.mesh,
+                        leaf_specs=leaf_specs, impl=impl)
 
 
 # ---------------------------------------------------------------------------
@@ -280,7 +331,7 @@ def delayed_delta(
         delta = sum_j b_j (pi_j(x_delayed) - x_delayed)
               = recv - (sum_j b_j) * sent
 
-    Applying ``x <- x + alpha * delta`` (via ``ops.gossip_apply`` with
+    Applying ``x <- x + alpha * delta`` (via ``apply_gossip`` with
     target ``x + delta``) is the delayed analogue of the masked mode's
     in-step correction; at consensus every pi_j(x) == x so delta == 0
     and the fixed points coincide.

@@ -114,7 +114,7 @@ def shard(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:
     """Constrain ``x`` per the current rules; identity when no rules are
     active, when ranks mismatch (e.g. under extra vmap dims), or when
     the spec resolves fully replicated (also keeps shard_map manual
-    bodies constraint-free, which jax 0.4.x requires)."""
+    bodies constraint-free)."""
     rules = current_rules()
     if rules is None or len(axes) != x.ndim:
         return x

@@ -110,7 +110,9 @@ def ssd(
 def _gossip_tree_map(x_tree, partner_tree, alpha: float, mode: str):
     """Shared leaf dispatcher for the consensus update x + alpha*(y - x).
     Non-float leaves pass through untouched. ``mode`` is already
-    resolved (one of :data:`MODES`)."""
+    resolved (one of :data:`MODES`). The kernel paths need a region
+    manual over every mesh axis (the compiler cannot partition a Mosaic
+    kernel); ``repro.dist.gossip.apply_gossip`` provides it."""
 
     def leaf(x, y):
         if not jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating):

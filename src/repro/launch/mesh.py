@@ -1,4 +1,9 @@
-"""Production meshes.
+"""Mesh construction.
+
+Every mesh of the runtime is built here, with every axis ``Auto``: the
+runtime leaves the non-node axes to the compiler inside its node-manual
+``shard_map`` regions, which ``jax.make_mesh``'s default ``Explicit``
+axes do not allow.
 
 Functions, not module constants: importing this module never touches
 jax device state. The dry-run launcher sets
@@ -8,6 +13,15 @@ import; smoke tests and benches see the real (single) device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False, shard: int = 1):
@@ -28,7 +42,7 @@ def make_production_mesh(*, multi_pod: bool = False, shard: int = 1):
     else:
         shape = (16,) + ((shard, model) if shard > 1 else (16,))
         axes = ("data",) + (("shard", "model") if shard > 1 else ("model",))
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(
@@ -42,11 +56,11 @@ def make_test_mesh(
     mid = () if shard is None else (int(shard),)
     mid_ax = () if shard is None else ("shard",)
     if multi_pod:
-        return jax.make_mesh(
+        return make_mesh(
             (2, nodes // 2) + mid + (model,),
             ("pod", "data") + mid_ax + ("model",),
         )
-    return jax.make_mesh((nodes,) + mid + (model,), ("data",) + mid_ax + ("model",))
+    return make_mesh((nodes,) + mid + (model,), ("data",) + mid_ax + ("model",))
 
 
 # Re-export: the node/shard-count authorities live at the dist layer

@@ -14,7 +14,6 @@ train driver's ``--trace`` (see ``docs/observability.md``).
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 
@@ -38,21 +37,24 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main():
-    args = build_parser().parse_args()
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
-    os.environ.setdefault(
-        "XLA_FLAGS",
-        f"--xla_force_host_platform_device_count={args.data_par * args.model_par}",
-    )
+    from repro.launch import runtime
+
+    runtime.force_host_devices(args.data_par * args.model_par)
     import jax
     import jax.numpy as jnp
     import numpy as np
+
+    runtime.init_compile_cache()
+    print(runtime.device_line())
 
     from repro.configs.registry import get_config, get_smoke_config
     from repro.data.pipeline import SyntheticCorpus
     from repro.dist import serve as sv
     from repro.dist import sharding as shd
+    from repro.launch.mesh import make_mesh
     from repro.models.transformer import Model
     from repro.telemetry import StepTimer, TraceRecorder
 
@@ -61,7 +63,7 @@ def main():
         else get_config(args.arch)
     )
     model = Model(cfg)
-    mesh = jax.make_mesh((args.data_par, args.model_par), ("data", "model"))
+    mesh = make_mesh((args.data_par, args.model_par), ("data", "model"))
     rules = shd.serve_rules(mesh, cfg)
     if args.batch % args.data_par:
         raise SystemExit("batch must divide data_par")

@@ -84,6 +84,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2_1_8b")
     ap.add_argument("--preset", default="tiny", choices=("tiny", "small", "full"))
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the preset's config to this many layers "
+                         "(0: the preset's depth; small is at most 8)")
     ap.add_argument("--graph", default="paper8")
     ap.add_argument("--nodes", type=int, default=8)
     ap.add_argument("--model-par", type=int, default=1)
@@ -166,9 +169,26 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main():
-    args = build_parser().parse_args()
+def main(argv=None):
+    """Run the driver on ``argv`` (default: ``sys.argv[1:]``).
 
+    Returns the run's final state for in-process callers
+    (``chip_smoke.py``): ``params`` (node-stacked), ``rows`` (the logged
+    steps), ``plan``/``schedule`` (``None`` for a one-node run),
+    ``bits`` (the last step's activation row) and ``spec``."""
+    args = build_parser().parse_args(argv)
+
+    if args.nodes < 1:
+        raise SystemExit(f"--nodes must be >= 1, got {args.nodes}")
+    if args.layers < 0:
+        raise SystemExit(f"--layers must be >= 0, got {args.layers}")
+    if args.nodes == 1 and args.mode != "local":
+        raise SystemExit(f"--mode {args.mode} gossips between nodes; one "
+                         "node has no topology, so --nodes 1 needs "
+                         "--mode local")
+    if args.nodes == 1 and args.shard > 1:
+        raise SystemExit("--nodes 1 runs the replicated runtime; "
+                         "--shard must be 1")
     if args.shard < 1:
         raise SystemExit(f"--shard must be >= 1, got {args.shard}")
     # "sequential" and "masked" are the same execution (every matching
@@ -189,13 +209,15 @@ def main():
             f"--shard {args.shard} (the node's batch splits over the "
             "shard axis)")
 
+    from repro.launch import runtime
+
     # device count must be set before jax import
-    ndev = args.nodes * args.shard * args.model_par
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={ndev}"
-    )
+    runtime.force_host_devices(args.nodes * args.shard * args.model_par)
     import jax
     import jax.numpy as jnp
+
+    runtime.init_compile_cache()
+    print(runtime.device_line())
 
     from repro.checkpoint import ckpt as ckpt_lib
     from repro.configs.registry import get_config, get_smoke_config
@@ -211,6 +233,7 @@ def main():
     )
     from repro.dist import fsdp
     from repro.dist import sharding as shd
+    from repro.launch.mesh import make_mesh
     from repro.models.transformer import Model
     from repro.optim.optimizers import sgd
 
@@ -223,22 +246,15 @@ def main():
             get_config(args.arch),
             num_layers=min(get_config(args.arch).num_layers, 8),
         )
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
 
-    graph = named_graph(args.graph, args.nodes, seed=3)
-    if graph.m != args.nodes:
-        raise SystemExit(f"graph has {graph.m} nodes, --nodes {args.nodes}")
+    def topology():
+        graph = named_graph(args.graph, args.nodes, seed=3)
+        if graph.m != args.nodes:
+            raise SystemExit(f"graph has {graph.m} nodes, --nodes {args.nodes}")
+        return graph
 
-    if args.mode == "vanilla":
-        plan = plan_vanilla(graph)
-        schedule = vanilla_schedule(plan.matchings, args.steps)
-    elif args.mode == "periodic":
-        plan, _ = plan_periodic(graph, args.budget)
-        schedule = periodic_schedule(plan.matchings, args.budget, args.steps)
-    else:
-        plan = plan_matcha(graph, args.budget, seed=args.seed)
-        schedule = plan.schedule(args.steps, seed=args.seed)
-
-    # --- fault injection (repro.faults) --------------------------------
     fault_spec = FaultSpec(
         p_drop=args.p_drop,
         straggler_prob=args.straggler_prob,
@@ -246,6 +262,23 @@ def main():
         crash_at_step=args.crash_at_step,
         seed=args.fault_seed,
     )
+    plan = schedule = None
+    if args.nodes == 1:
+        # local SGD on one node: no topology, no plan, no schedule
+        if not fault_spec.empty:
+            raise SystemExit("fault injection draws from a topology's "
+                             "matchings; --nodes 1 has none")
+    elif args.mode == "vanilla":
+        plan = plan_vanilla(topology())
+        schedule = vanilla_schedule(plan.matchings, args.steps)
+    elif args.mode == "periodic":
+        plan, _ = plan_periodic(topology(), args.budget)
+        schedule = periodic_schedule(plan.matchings, args.budget, args.steps)
+    else:
+        plan = plan_matcha(topology(), args.budget, seed=args.seed)
+        schedule = plan.schedule(args.steps, seed=args.seed)
+
+    # --- fault injection (repro.faults) --------------------------------
     fault_sched = None
     faulted = fault_spec.has_link_faults
     if not fault_spec.empty:
@@ -272,12 +305,12 @@ def main():
               "faults guarantee")
 
     if use_fsdp:
-        mesh = jax.make_mesh(
+        mesh = make_mesh(
             (args.nodes, args.shard, args.model_par),
             ("data", "shard", "model"),
         )
     else:
-        mesh = jax.make_mesh((args.nodes, args.model_par), ("data", "model"))
+        mesh = make_mesh((args.nodes, args.model_par), ("data", "model"))
     model = Model(cfg)
     opt = sgd(args.lr, momentum=args.momentum)
     spec = dt.make_spec(mesh, cfg, multi_pod=False)
@@ -323,8 +356,18 @@ def main():
                       "one stack-at-once gather; drop the flag to "
                       "stream per scan iteration")
     else:
-        params = dt.init_stacked_params(model, spec, seed=args.seed)
-        opt_state = dt.init_stacked_opt_state(opt, model, spec)
+        pspecs = dt.stacked_param_shardings(model, spec)
+        ospecs = dt.stacked_opt_shardings(opt, model, spec, pspecs)
+        # each node's replica is made on its own device: stacking on
+        # one device first would need every node's state there
+        params = jax.jit(
+            lambda: dt.init_stacked_params(model, spec, seed=args.seed),
+            out_shardings=shd.named_shardings(pspecs, mesh),
+        )()
+        opt_state = jax.jit(
+            lambda: dt.init_stacked_opt_state(opt, model, spec),
+            out_shardings=shd.named_shardings(ospecs, mesh),
+        )()
     start_step = 0
     resume_dir = args.resume
     if resume_dir == "auto":
@@ -352,15 +395,15 @@ def main():
     if use_fsdp:
         pspecs = fsdp.fsdp_param_pspecs(spec, layout)
         ospecs = fsdp.fsdp_opt_pspecs(opt, spec, layout)
-    else:
-        pspecs = dt.stacked_param_shardings(model, spec)
-        ospecs = None
     with jax.set_mesh(mesh):
-        params = jax.device_put(params, shd.named_shardings(pspecs, mesh))
-        if ospecs is not None:
+        # the steps donate params and opt_state: from here on only the
+        # newest step output is read
+        if use_fsdp or resume_dir:
+            # fresh replicated state was made in place above
+            params = jax.device_put(
+                params, shd.named_shardings(pspecs, mesh))
             opt_state = jax.device_put(
-                opt_state, shd.named_shardings(ospecs, mesh)
-            )
+                opt_state, shd.named_shardings(ospecs, mesh))
         gossip_mode = (
             "none" if args.mode == "local" else args.gossip_mode
         )
@@ -394,7 +437,7 @@ def main():
             else:
                 bplan = dt.param_bucket_plan(model)
                 gstate = dt.init_gossip_state(plan, spec, bplan)
-                flush = dt.make_gossip_flush(plan, spec, bplan)
+                flush = dt.make_gossip_flush(model, plan, spec, bplan)
         step_cache = {}
 
         def get_step(active):
@@ -460,7 +503,7 @@ def main():
         # with the modeled per-matching bytes from analysis.bytes_model
         matching_ms = {}
         per_matching_bytes = 0
-        if traced:
+        if traced and plan is not None:
             from repro.analysis import bytes_model
             from repro.telemetry import probes as tprobes
 
@@ -488,9 +531,12 @@ def main():
         trace_rows = []
         sim_time = 0.0
         t0 = time.time()
+        num_matchings = 0 if plan is None else plan.num_matchings
+        bits = jnp.zeros((num_matchings,), jnp.float32)
         for k in range(start_step, args.steps):
             batch = next(it)
-            active = schedule.active_indices(k)
+            active = () if schedule is None else schedule.active_indices(k)
+            comm_units = 0.0 if schedule is None else schedule.comm_units(k)
             if faulted:
                 # per-node effective rows: activation bit x link-survival
                 # gate, symmetric across every matching edge (a dropped
@@ -498,7 +544,7 @@ def main():
                 bits = jnp.asarray(
                     fault_sched.node_bits(schedule.activations[k], k)
                 )
-            else:
+            elif schedule is not None:
                 bits = jnp.asarray(
                     schedule.activations[k].astype(np.float32)
                 )
@@ -511,18 +557,18 @@ def main():
                     )
                     # delayed gossip hides behind compute: the step costs
                     # the slower of the two, not their sum
-                    sim_time += max(schedule.comm_units(k), 1.0)
+                    sim_time += max(comm_units, 1.0)
                 elif phased:
                     params, opt_state, losses, metrics = stepf(
                         params, opt_state, batch, bits, step=k
                     )
-                    sim_time += schedule.comm_units(k) + 1.0
+                    sim_time += comm_units + 1.0
                 else:
                     params, opt_state, losses, metrics = stepf(
                         params, opt_state, batch, bits
                     )
                     # paper's delay model: one unit per activated matching
-                    sim_time += schedule.comm_units(k) + 1.0   # +1 compute
+                    sim_time += comm_units + 1.0   # +1 compute
                 sp.fence((params, losses))
             if fault_sched is not None:
                 # stragglers stretch the simulated clock: the paper's
@@ -565,12 +611,12 @@ def main():
                 cons = float(consensus(params))
                 rows.append(
                     dict(step=k, loss=loss_mean, consensus=cons,
-                         sim_time=sim_time, comm_units=schedule.comm_units(k),
+                         sim_time=sim_time, comm_units=comm_units,
                          wall=time.time() - t0)
                 )
                 print(
                     f"step {k:4d} loss {loss_mean:.4f} consensus {cons:.3e} "
-                    f"sim_time {sim_time:.0f}u active {len(active)}/{plan.num_matchings}"
+                    f"sim_time {sim_time:.0f}u active {len(active)}/{num_matchings}"
                 )
             if args.ckpt_every and args.ckpt_dir and (k + 1) % args.ckpt_every == 0:
                 # overlap: checkpoints land the in-flight exchange (the
@@ -634,6 +680,8 @@ def main():
             print(f"wrote trace: {jsonl_path} + {chrome_path} "
                   f"({len(recorder.events())} events, "
                   f"{recorder.num_dropped} dropped) and {metrics_path}")
+    return dict(params=params, rows=rows, plan=plan, schedule=schedule,
+                bits=bits, spec=spec)
 
 
 if __name__ == "__main__":

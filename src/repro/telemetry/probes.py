@@ -129,6 +129,7 @@ def measure_matchings(
             in_specs=pspec,
             out_specs=pspec,
             axis_names=manual,
+            check_vma=False,
         ))
         summary = _probe_loop(
             timer, f"gossip/matching{j}", lambda p=probe: p(x),
@@ -201,10 +202,12 @@ def measure_fsdp_collectives(
     gather = jax.jit(jax.shard_map(
         gather_body, mesh=spec.mesh, in_specs=pspec,
         out_specs=P(nodes_ax, "shard"), axis_names=manual,
+        check_vma=False,
     ))
     rs = jax.jit(jax.shard_map(
         rs_body, mesh=spec.mesh, in_specs=pspec, out_specs=pspec,
         axis_names=manual,
+        check_vma=False,
     ))
     out = {}
     out["gather"] = _probe_loop(

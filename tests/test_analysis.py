@@ -231,10 +231,12 @@ def test_traced_ring_shift_ppermute_is_flagged():
         from repro.analysis import checks
         from repro.analysis.collectives import collect
 
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
 
         @partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
-                 out_specs=P("data"))
+                 out_specs=P("data"),
+                 check_vma=False)
         def bad_gossip(x):
             # ring shift: a legal ppermute, an illegal matching
             return jax.lax.ppermute(
@@ -293,12 +295,14 @@ def test_unwhitelisted_dist_layer_upcast_is_flagged():
         import numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         info = gossip.NodeAxisInfo(("data",), 4)
         perms = np.array([[1, 0, 3, 2]])
 
         @partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
-                 out_specs=P("data"))
+                 out_specs=P("data"),
+                 check_vma=False)
         def step(x):
             return gossip.mix_matchings_masked(
                 x, 0.5, perms, jnp.ones((1,), jnp.float32), info)

@@ -15,6 +15,7 @@ import pytest
 from repro.checkpoint import ckpt
 from repro.configs.registry import get_smoke_config
 from repro.dist import decen_train as dt
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import Model
 from repro.optim.optimizers import sgd
 
@@ -178,7 +179,7 @@ def test_find_resumable_empty_and_missing(tmp_path):
 def test_stacked_state_roundtrip(tmp_path, per_node_files):
     cfg = get_smoke_config("internlm2_1_8b")
     model = Model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     spec = dt.make_spec(mesh, cfg, multi_pod=False)
     # fake a 4-node run on the single local device: stacked state only
     spec = dataclasses.replace(spec, num_nodes=4)

@@ -328,7 +328,8 @@ def test_gossip_under_drops_matches_dense_oracle():
             with jax.set_mesh(mesh):
                 f = jax.shard_map(body, mesh=mesh,
                                   in_specs=(specs, P("data")),
-                                  out_specs=specs, axis_names={"data"})
+                                  out_specs=specs, axis_names={"data"},
+                                  check_vma=False)
                 got = jax.jit(f)(x, jnp.asarray(ebits))
             W = effective_mixing_matrix(
                 np.asarray(plan.permutations), plan.alpha, ebits)

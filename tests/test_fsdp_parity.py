@@ -46,9 +46,10 @@ def run_sub(body: str, timeout: int = 900) -> str:
 
 def test_shard1_replays_replicated_trajectory_exactly():
     """A size-1 shard axis selects the fsdp runtime but must reproduce
-    the replicated masked trajectory bit-for-bit (fp32 params): the
+    the replicated masked trajectory (fp32 params): the
     all-gather/reduce-scatter degenerate to identity and every update is
-    the same elementwise arithmetic in bucket layout."""
+    the same elementwise arithmetic in bucket layout. The first step is
+    bit-identical; later steps agree to a few ulps."""
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs.base import ModelConfig
@@ -84,6 +85,8 @@ def test_shard1_replays_replicated_trajectory_exactly():
             for k in range(K):
                 params, opt_state, lu, _ = step(
                     params, opt_state, batches[k], bits_rows[k])
+                if k == 0:
+                    p_ref1 = jax.device_get(params)
         p_ref = jax.device_get(params)
 
         mesh_f = make_test_mesh(nodes=4, model=1, shard=1)
@@ -98,12 +101,23 @@ def test_shard1_replays_replicated_trajectory_exactly():
             for k in range(K):
                 shards, fopt, lf, _ = step(
                     shards, fopt, batches[k], bits_rows[k])
+                if k == 0:
+                    p_f1 = jax.device_get(fsdp.gather_params(layout, shards))
         p_f = jax.device_get(fsdp.gather_params(layout, shards))
 
-        for a, b in zip(jax.tree.leaves(p_ref), jax.tree.leaves(p_f)):
+        for a, b in zip(jax.tree.leaves(p_ref1), jax.tree.leaves(p_f1)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        np.testing.assert_array_equal(
-            np.asarray(lu).ravel(), np.asarray(lf)[:, 0])
+        # then within a few fp32 ulps, not bitwise. The first difference
+        # is at step 2, in the tied embedding table alone (7.5e-9, one
+        # ulp at its scale): the one leaf whose gradient sums two terms
+        # (the lookup's scatter-add, the head's matmul), which the two
+        # programs add in different orders. By step 5 it has spread to
+        # 1.2e-7 (one ulp at 1.0, on the norm scales); 1e-6 is 8x that.
+        for a, b in zip(jax.tree.leaves(p_ref), jax.tree.leaves(p_f)):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(
+            np.asarray(lu).ravel(), np.asarray(lf)[:, 0], rtol=1e-6)
         print("OK")
     """)
     assert "OK" in out
@@ -167,7 +181,7 @@ def test_shard2_parity_sequential_and_overlap():
                         params, opt_state, _, _ = step(
                             params, opt_state, batches[k], bits_rows[k])
                 if mode == "overlap":
-                    params = dt.make_gossip_flush(plan, spec_u, bplan)(
+                    params = dt.make_gossip_flush(model, plan, spec_u, bplan)(
                         params, gstate)
                 refs[mode] = jax.device_get(params)
 

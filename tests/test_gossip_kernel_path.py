@@ -57,7 +57,8 @@ def test_pallas_gossip_path_matches_ref_inside_mix_matchings():
                 ex = lambda t: jax.tree.map(lambda a: a[None], t)
                 return ex(out_s), ex(out_m)
             f = jax.shard_map(body, mesh=mesh, in_specs=(specs, P()),
-                              out_specs=(specs, specs), axis_names={"data"})
+                              out_specs=(specs, specs), axis_names={"data"},
+                              check_vma=False)
             return jax.jit(f)(x, bits)
 
         with jax.set_mesh(mesh):
@@ -79,6 +80,62 @@ def test_pallas_gossip_path_matches_ref_inside_mix_matchings():
         want = mix_dense(x, jnp.asarray(W))
         for a, b in zip(jax.tree.leaves(pallas_s), jax.tree.leaves(want)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+        print("OK")
+    """)
+    assert "OK" in out
+
+
+def test_tensor_parallel_leaves_update_shard_by_shard():
+    """On a (4 nodes x 2 model) mesh the kernel region takes each leaf's
+    model-axis spec: a tensor-parallel leaf is updated on its local
+    shards (no all-gather in the program), and the exchange still equals
+    the dense mixing oracle. Without the specs the leaves are gathered,
+    which shows the check can see a gather."""
+    out = run_sub("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.core import plan_matcha, ring_graph
+        from repro.dist.gossip import (
+            NodeAxisInfo, mix_dense, mix_matchings_masked,
+        )
+        from repro.launch.mesh import make_test_mesh
+
+        mesh = make_test_mesh(nodes=4, model=2)
+        plan = plan_matcha(ring_graph(4), 0.5, seed=0)
+        info = NodeAxisInfo(axis_names=("data",), num_nodes=4)
+        x = {"w": jax.random.normal(jax.random.key(0), (4, 32, 6)),
+             "b": jax.random.normal(jax.random.key(1), (4, 10))}
+        stacked = {"w": P("data", "model"), "b": P("data")}
+        local = {"w": P("model"), "b": P()}
+        bits = jnp.ones((plan.num_matchings,), jnp.float32)
+
+        def run(leaf_specs):
+            def body(xs, bits):
+                mine = jax.tree.map(lambda a: a[0], xs)
+                out = mix_matchings_masked(
+                    mine, plan.alpha, plan.permutations, bits, info,
+                    impl="interpret", leaf_specs=leaf_specs)
+                return jax.tree.map(lambda a: a[None], out)
+            f = jax.jit(jax.shard_map(
+                body, mesh=mesh, in_specs=(P("data"), P()),
+                out_specs=P("data"), axis_names={"data"}, check_vma=False))
+            xs = jax.device_put(x, {k: NamedSharding(mesh, s)
+                                    for k, s in stacked.items()})
+            text = f.lower(xs, bits).compile().as_text()
+            return f(xs, bits), text
+
+        with jax.set_mesh(mesh):
+            got, text = run(local)
+            _, gathered = run(None)
+        assert "all-gather" not in text
+        assert "all-gather" in gathered
+
+        L = sum(m.laplacian() for m in plan.matchings)
+        W = np.eye(4) - plan.alpha * L
+        want = mix_dense(x, jnp.asarray(W, jnp.float32))
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-6, rtol=0)
         print("OK")
     """)
     assert "OK" in out

@@ -72,6 +72,7 @@ def test_param_groups_cover_tree_in_execution_order():
     from repro.configs.registry import get_smoke_config
     from repro.dist import decen_train as dt
     from repro.dist import fsdp
+    from repro.launch.mesh import make_mesh
     from repro.models.transformer import Model
 
     cfg = get_smoke_config("internlm2_1_8b")
@@ -88,7 +89,7 @@ def test_param_groups_cover_tree_in_execution_order():
     per_layer = [g for g in specs if g.layer is not None]
     assert {g.layer for g in per_layer if g.keys == ("blocks_0",)} == {0, 1}
 
-    mesh = jax.make_mesh((1, 1, 1), ("data", "shard", "model"))
+    mesh = make_mesh((1, 1, 1), ("data", "shard", "model"))
     spec = dt.make_spec(mesh, cfg)
     layout = fsdp.make_stream_layout(model, spec)
     assert layout.plan.names == tuple(names)
@@ -112,12 +113,13 @@ def test_stream_cross_layout_checkpoint_restore():
     from repro.configs.registry import get_smoke_config
     from repro.dist import decen_train as dt
     from repro.dist import fsdp
+    from repro.launch.mesh import make_mesh
     from repro.models.transformer import Model
     from repro.optim.optimizers import sgd
 
     cfg = get_smoke_config("internlm2_1_8b")
     model = Model(cfg)
-    mesh = jax.make_mesh((1, 1, 1), ("data", "shard", "model"))
+    mesh = make_mesh((1, 1, 1), ("data", "shard", "model"))
     spec = dt.make_spec(mesh, cfg)
     opt = sgd(0.1, momentum=0.9)
     s_layout = fsdp.make_stream_layout(model, spec)
