@@ -1,0 +1,10 @@
+"""Device time per step of the ops under scope ``fwd_bwd`` that are
+also under scope ``head``, the head (final norm, unembedding,
+cross-entropy), on the chip where it is longest."""
+from bench.trace import in_scope
+
+
+def read(ctx):
+    ms = ctx.scope_ms(lambda op: in_scope(op.scope, "fwd_bwd")
+                      and in_scope(op.scope, "head"))
+    return ms or None

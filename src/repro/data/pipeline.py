@@ -152,26 +152,31 @@ class DecentralizedBatches:
         ])
 
     def __next__(self) -> Dict[str, jax.Array]:
+        """The next batch. The host's sampling and the transfer to the
+        device are profiler spans (``data/sample``, ``data/put``), so a
+        trace of the train launcher puts a device idle gap down to one or
+        the other."""
         N, B, S = self.num_nodes, self.batch_per_node, self.seq_len
-        toks = np.zeros((N, B, S + 1), np.int32)
-        for n in range(N):
-            prior = None if self.node_priors is None else self.node_priors[n]
-            for b in range(B):
-                toks[n, b] = self.corpus.sample(
-                    self.node_rngs[n], S + 1, state_prior=prior
-                )
-        batch = {
-            "tokens": jnp.asarray(toks[..., :-1]),
-            "labels": jnp.asarray(toks[..., 1:]),
-        }
-        if self.cfg.frontend == "vision":
-            batch["prefix_embeddings"] = jnp.asarray(
-                self._frontend_stub(), dtype=jnp.bfloat16
-            )
-        if self.cfg.frontend == "audio":
-            batch["encoder_frames"] = jnp.asarray(
-                self._frontend_stub(), dtype=jnp.bfloat16
-            )
+        span = jax.profiler.TraceAnnotation
+        with span("data/sample"):
+            toks = np.zeros((N, B, S + 1), np.int32)
+            for n in range(N):
+                prior = (None if self.node_priors is None
+                         else self.node_priors[n])
+                for b in range(B):
+                    toks[n, b] = self.corpus.sample(
+                        self.node_rngs[n], S + 1, state_prior=prior
+                    )
+            stub_key = {"vision": "prefix_embeddings",
+                        "audio": "encoder_frames"}.get(self.cfg.frontend)
+            stub = self._frontend_stub() if stub_key else None
+        with span("data/put"):
+            batch = {
+                "tokens": jnp.asarray(toks[..., :-1]),
+                "labels": jnp.asarray(toks[..., 1:]),
+            }
+            if stub_key:
+                batch[stub_key] = jnp.asarray(stub, dtype=jnp.bfloat16)
         return batch
 
 

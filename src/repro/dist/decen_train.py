@@ -262,13 +262,14 @@ def _apply_delayed(
     ``x <- x + alpha * delta`` through the fused gossip-axpy (the one
     definition both the train step and the end-of-run flush use — they
     must stay identical for flushed checkpoints to resume exactly)."""
-    delta_tree = bucketing.unravel(bplan, delta_buckets)
-    target = jax.tree.map(
-        lambda x, d: x if d is None else x.astype(jnp.float32) + d,
-        p, delta_tree,
-    )
-    return apply_gossip(p, target, alpha, mesh=spec.mesh,
-                        leaf_specs=leaf_specs)
+    with jax.named_scope("gossip/update"):
+        delta_tree = bucketing.unravel(bplan, delta_buckets)
+        target = jax.tree.map(
+            lambda x, d: x if d is None else x.astype(jnp.float32) + d,
+            p, delta_tree,
+        )
+        return apply_gossip(p, target, alpha, mesh=spec.mesh,
+                            leaf_specs=leaf_specs)
 
 
 def _reject_shard_mesh(spec: DistSpec, what: str) -> None:
@@ -477,147 +478,3 @@ def make_train_step(
     )
     return jax.jit(stepped, donate_argnums=(0, 1))
 
-
-# ---------------------------------------------------------------------------
-# Phased train step (telemetry)
-# ---------------------------------------------------------------------------
-def make_phased_train_step(
-    model,
-    opt: Optimizer,
-    plan,
-    spec: DistSpec,
-    *,
-    timer=None,
-    gossip_mode: str = "masked",
-    active: Sequence[int] = (),
-    grad_clip: float = 0.0,
-    faulted: bool = False,
-):
-    """Telemetry variant of :func:`make_train_step`: the same update,
-    split into separately jitted + fenced phase executables so a host
-    clock can attribute wall time per runtime phase.
-
-    Same call signature and semantics as the fused step for
-    ``gossip_mode`` in ("masked", "static", "none")::
-
-        params, opt_state, losses, metrics = step(params, opt_state,
-                                                  batch, bits, step=k)
-
-    but executed as three fenced executables — ``fwd_bwd`` (grads +
-    clip), ``optimizer`` (update + apply), ``gossip`` (the matching
-    exchange; absent for "none") — each wrapped in a ``timer``
-    span (``repro.telemetry.StepTimer``; ``None`` times without
-    recording). After each call ``step.last_phase_ms`` holds the
-    phase-name → milliseconds dict of that call.
-
-    The phase boundaries are real fences: per-phase numbers cost
-    dispatch serialization and one extra grads round-trip, so this
-    builder is only used when ``--trace`` is on. ``overlap`` mode is
-    deliberately unsupported — fencing its phases would serialize the
-    very collective/compute overlap being measured; overlap runs get
-    whole-step timing plus per-matching probes instead
-    (``docs/observability.md``).
-    """
-    from repro.telemetry.timers import StepTimer
-
-    if gossip_mode == "sequential":
-        gossip_mode = "masked"
-    if gossip_mode not in ("masked", "static", "none"):
-        raise ValueError(
-            "make_phased_train_step supports gossip_mode in "
-            f"('masked', 'static', 'none'); got {gossip_mode!r} "
-            "(overlap runs are timed whole-step: fencing phases would "
-            "serialize the overlap being measured)"
-        )
-    _reject_shard_mesh(spec, "make_phased_train_step")
-    if plan is None and gossip_mode != "none":
-        raise ValueError(f"gossip_mode {gossip_mode!r} needs a plan")
-    timer = timer or StepTimer()
-    info = spec.node_info
-    leaf_specs = local_param_pspecs(model, spec)
-    nodes_ax = spec.nodes_axis
-    mesh = spec.mesh
-    if plan is not None:
-        perms = np.asarray(plan.permutations)
-        alpha = float(plan.alpha)
-    active = tuple(int(j) for j in active)
-    expand = lambda t: jax.tree.map(lambda a: a[None], t)
-    manual = set(spec.node_axes)
-
-    def fwd_bwd_body(params, batch):
-        p = jax.tree.map(lambda a: a[0], params)
-        b = jax.tree.map(lambda a: a[0], batch)
-        (loss, metrics), grads = jax.value_and_grad(
-            model.loss, has_aux=True
-        )(p, b)
-        if grad_clip:
-            grads = clip_by_global_norm(grads, grad_clip)
-        return expand(grads), loss[None], expand(metrics)
-
-    def opt_body(params, opt_state, grads):
-        p = jax.tree.map(lambda a: a[0], params)
-        s = jax.tree.map(lambda a: a[0], opt_state)
-        g = jax.tree.map(lambda a: a[0], grads)
-        updates, s = opt.update(g, s, p)
-        return expand(apply_updates(p, updates)), expand(s)
-
-    def gossip_body(params, bits):
-        p = jax.tree.map(lambda a: a[0], params)
-        if faulted:
-            bits = bits[0]            # (nodes, M) -> this node's (M,) row
-        if gossip_mode == "masked":
-            p = mix_matchings_masked(
-                p, alpha, perms, bits, info, leaf_specs=leaf_specs)
-        else:
-            p = mix_matchings(
-                p, alpha, perms, active, info,
-                gate_bits=bits if faulted else None,
-                leaf_specs=leaf_specs,
-            )
-        return expand(p)
-
-    fwd_bwd = jax.jit(jax.shard_map(
-        fwd_bwd_body, mesh=mesh,
-        in_specs=(P(nodes_ax), P(nodes_ax)),
-        out_specs=(P(nodes_ax), P(nodes_ax), P(nodes_ax)),
-        axis_names=manual,
-        check_vma=False,
-    ))
-    optimizer = jax.jit(jax.shard_map(
-        opt_body, mesh=mesh,
-        in_specs=(P(nodes_ax), P(nodes_ax), P(nodes_ax)),
-        out_specs=(P(nodes_ax), P(nodes_ax)),
-        axis_names=manual,
-        check_vma=False,
-    ))
-    gossip = None
-    if gossip_mode != "none":
-        gossip = jax.jit(jax.shard_map(
-            gossip_body, mesh=mesh,
-            in_specs=(P(nodes_ax), P(nodes_ax) if faulted else P()),
-            out_specs=P(nodes_ax),
-            axis_names=manual,
-            check_vma=False,
-        ))
-
-    def step(params, opt_state, batch, bits, *, step: int = -1):
-        phase_ms = {}
-        (grads, losses, metrics), phase_ms["fwd_bwd"] = timer.measure(
-            "fwd_bwd", lambda: fwd_bwd(params, batch),
-            cat="phase", step=step, tid=0,
-        )
-        (params, opt_state), phase_ms["optimizer"] = timer.measure(
-            "optimizer", lambda: optimizer(params, opt_state, grads),
-            cat="phase", step=step, tid=0,
-        )
-        if gossip is not None:
-            params, phase_ms["gossip"] = timer.measure(
-                "gossip", lambda: gossip(params, bits),
-                cat="phase", step=step, tid=0,
-            )
-        step_wrapper.last_phase_ms = phase_ms
-        return params, opt_state, losses, metrics
-
-    step_wrapper = step
-    step_wrapper.last_phase_ms = {}
-    return step_wrapper

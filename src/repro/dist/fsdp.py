@@ -935,154 +935,6 @@ def make_fsdp_train_step(
     return jax.jit(stepped, donate_argnums=(0, 1))
 
 
-def make_phased_fsdp_train_step(
-    model,
-    opt: Optimizer,
-    plan,
-    spec: DistSpec,
-    layout: AnyFsdpLayout,
-    *,
-    timer=None,
-    gossip_mode: str = "sequential",
-    grad_clip: float = 0.0,
-    faulted: bool = False,
-):
-    """Telemetry variant of :func:`make_fsdp_train_step`: the same
-    update split into separately jitted + fenced executables —
-    ``fwd_bwd`` (materialize + grads + clip; the all-gather and grad
-    reduce-scatter live inside it, since splitting them out would
-    require holding the full gathered tree across an executable
-    boundary, i.e. the O(model) copy the shard axis exists to remove),
-    ``optimizer``, and ``gossip`` — so a host clock can attribute wall
-    time per phase. The *isolated* gather / reduce-scatter costs come
-    from ``repro.telemetry.probes.measure_fsdp_collectives`` instead.
-
-    Same call signature as the fused step for ``gossip_mode`` in
-    ("sequential", "none")::
-
-        shards, opt_state, losses, metrics = step(shards, opt_state,
-                                                  batch, bits, step=k)
-
-    ``timer`` is a ``repro.telemetry.StepTimer`` (``None`` times without
-    recording); after each call ``step.last_phase_ms`` holds that call's
-    phase-name → milliseconds dict. ``overlap`` is unsupported for the
-    same reason as in ``decen_train.make_phased_train_step``: fencing
-    would serialize the overlap under measurement.
-    """
-    from repro.telemetry.timers import StepTimer
-
-    if gossip_mode == "masked":
-        gossip_mode = "sequential"
-    if gossip_mode not in ("sequential", "none"):
-        raise ValueError(
-            "make_phased_fsdp_train_step supports gossip_mode in "
-            f"('sequential', 'none'); got {gossip_mode!r} "
-            "(overlap runs are timed whole-step: fencing phases would "
-            "serialize the overlap being measured)"
-        )
-    if spec.num_shards != layout.num_shards:
-        raise ValueError(
-            f"spec mesh has shard factor {spec.num_shards} but the layout "
-            f"was built for {layout.num_shards}"
-        )
-    timer = timer or StepTimer()
-    info = spec.node_info
-    nodes_ax = spec.nodes_axis
-    mesh = spec.mesh
-    manual = set(spec.node_axes) | {"shard"}
-    perms = np.asarray(plan.permutations)
-    alpha = float(plan.alpha)
-    streaming = isinstance(layout, FsdpStreamLayout)
-    num_shards = layout.num_shards
-    ex2 = lambda t: jax.tree.map(lambda a: a[None, None], t)
-
-    def fwd_bwd_body(shards, batch):
-        ps = tuple(a[0, 0] for a in shards)
-        b = jax.tree.map(lambda a: a[0], batch)
-        if streaming:
-            (loss, metrics), g = jax.value_and_grad(
-                lambda sh: _stream_loss(model, layout, sh, b), has_aux=True
-            )(ps)
-            if num_shards > 1:
-                g = tuple(x / num_shards for x in g)
-        else:
-            p = _materialize(layout, ps)
-            (loss, metrics), grads = jax.value_and_grad(
-                model.loss, has_aux=True
-            )(p, b)
-            g = _reduce_scatter_grads(layout, grads)
-        if grad_clip:
-            g = _clip_sharded(g, grad_clip)
-        loss = jax.lax.pmean(loss, "shard")
-        metrics = jax.tree.map(lambda m: jax.lax.pmean(m, "shard"), metrics)
-        return ex2(g), loss[None, None], ex2(metrics)
-
-    def opt_body(shards, opt_state, g_shards):
-        ps = tuple(a[0, 0] for a in shards)
-        s = jax.tree.map(lambda a: a[0, 0], opt_state)
-        g = tuple(a[0, 0] for a in g_shards)
-        updates, s = opt.update(g, s, ps)
-        return ex2(apply_updates(ps, updates)), ex2(s)
-
-    def gossip_body(shards, bits):
-        ps = tuple(a[0, 0] for a in shards)
-        if faulted:
-            bits = bits[0]            # (nodes, M) -> this node's (M,) row
-        ps = mix_matchings_masked(ps, alpha, perms, bits, info)
-        return ex2(ps)
-
-    pspec = tuple(P(nodes_ax, "shard") for _ in range(layout.plan.num_buckets))
-    batch_spec = P(nodes_ax, "shard")
-    opt_spec = fsdp_opt_pspecs(opt, spec, layout)
-    ls_spec = P(nodes_ax, "shard")
-
-    fwd_bwd = jax.jit(jax.shard_map(
-        fwd_bwd_body, mesh=mesh,
-        in_specs=(pspec, batch_spec),
-        out_specs=(pspec, ls_spec, ls_spec),
-        axis_names=manual,
-        check_vma=False,
-    ))
-    optimizer = jax.jit(jax.shard_map(
-        opt_body, mesh=mesh,
-        in_specs=(pspec, opt_spec, pspec),
-        out_specs=(pspec, opt_spec),
-        axis_names=manual,
-        check_vma=False,
-    ))
-    gossip = None
-    if gossip_mode != "none":
-        gossip = jax.jit(jax.shard_map(
-            gossip_body, mesh=mesh,
-            in_specs=(pspec, P(nodes_ax) if faulted else P()),
-            out_specs=pspec,
-            axis_names=manual,
-            check_vma=False,
-        ))
-
-    def step(shards, opt_state, batch, bits, *, step: int = -1):
-        phase_ms = {}
-        (g_shards, losses, metrics), phase_ms["fwd_bwd"] = timer.measure(
-            "fwd_bwd", lambda: fwd_bwd(shards, batch),
-            cat="phase", step=step, tid=0,
-        )
-        (shards, opt_state), phase_ms["optimizer"] = timer.measure(
-            "optimizer", lambda: optimizer(shards, opt_state, g_shards),
-            cat="phase", step=step, tid=0,
-        )
-        if gossip is not None:
-            shards, phase_ms["gossip"] = timer.measure(
-                "gossip", lambda: gossip(shards, bits),
-                cat="phase", step=step, tid=0,
-            )
-        step_wrapper.last_phase_ms = phase_ms
-        return shards, opt_state, losses, metrics
-
-    step_wrapper = step
-    step_wrapper.last_phase_ms = {}
-    return step_wrapper
-
-
 def make_fsdp_gossip_flush(plan, spec: DistSpec, layout: AnyFsdpLayout):
     """Land the exchange still in flight after the last overlap step,
     directly on the shards: ``shards = flush(shards, gstate)`` — the

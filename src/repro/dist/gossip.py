@@ -181,6 +181,18 @@ def apply_gossip(
     )(local, targets)
 
 
+def _exchange(x, axis_name, ids: Sequence[int], pair_lists) -> list:
+    """``x``'s partner copies, one ``ppermute`` per matching, each under
+    its own ``gossip/matching{j}`` scope. The arithmetic that combines
+    them runs under ``gossip/update`` at the caller, so a device trace
+    tells the exchange from the consensus update."""
+    out = []
+    for j, pairs in zip(ids, pair_lists):
+        with jax.named_scope(f"gossip/matching{j}"):
+            out.append(jax.lax.ppermute(x, axis_name, pairs))
+    return out
+
+
 def mix_matchings(
     local: PyTree,
     alpha: float,
@@ -217,29 +229,29 @@ def mix_matchings(
     def partner_target(x):
         if not _is_float(x):
             return x
-        if gate_bits is None:
-            acc = None
-            for j, pairs in zip(active, pair_lists):
-                with jax.named_scope(f"gossip/matching{j}"):
-                    p = jax.lax.ppermute(x, name, pairs).astype(jnp.float32)
-                acc = p if acc is None else acc + p
-            # y with x + alpha*(y - x) == x + alpha * sum_j (partner_j - x)
-            return acc - (k - 1.0) * x.astype(jnp.float32)
-        # degraded path: every active exchange still runs (same
-        # collective inventory), its delta scaled by the node's gate
-        xf = x.astype(jnp.float32)
-        delta = jnp.zeros_like(xf)
-        for j, pairs in zip(active, pair_lists):
-            with jax.named_scope(f"gossip/matching{j}"):
-                p = jax.lax.ppermute(x, name, pairs)
-            delta = delta + gate_bits[j].astype(jnp.float32) * (
-                p.astype(jnp.float32) - xf
-            )
-        return xf + delta
+        received = _exchange(x, name, active, pair_lists)
+        with jax.named_scope("gossip/update"):
+            if gate_bits is None:
+                acc = None
+                for p in received:
+                    p = p.astype(jnp.float32)
+                    acc = p if acc is None else acc + p
+                # y with x + alpha*(y - x) == x + alpha * sum_j (partner_j - x)
+                return acc - (k - 1.0) * x.astype(jnp.float32)
+            # degraded path: every active exchange still runs (same
+            # collective inventory), its delta scaled by the node's gate
+            xf = x.astype(jnp.float32)
+            delta = jnp.zeros_like(xf)
+            for j, p in zip(active, received):
+                delta = delta + gate_bits[j].astype(jnp.float32) * (
+                    p.astype(jnp.float32) - xf
+                )
+            return xf + delta
 
     targets = jax.tree.map(partner_target, local)
-    return apply_gossip(local, targets, float(alpha), mesh=info.mesh,
-                        leaf_specs=leaf_specs, impl=impl)
+    with jax.named_scope("gossip/update"):
+        return apply_gossip(local, targets, float(alpha), mesh=info.mesh,
+                            leaf_specs=leaf_specs, impl=impl)
 
 
 def mix_matchings_masked(
@@ -270,22 +282,24 @@ def mix_matchings_masked(
     def partner_target(x):
         if not _is_float(x):
             return x
-        xf = x.astype(jnp.float32)
-        delta = jnp.zeros_like(xf)
-        for j, pairs in enumerate(pair_lists):
-            with jax.named_scope(f"gossip/matching{j}"):
-                p = jax.lax.ppermute(x, name, pairs)
-            delta = delta + bits[j].astype(jnp.float32) * (
-                p.astype(jnp.float32) - xf
-            )
-        # y with x + alpha*(y - x) == x + alpha * sum_j b_j (partner_j - x)
-        # (kept fp32 like the static path: rounding the target to x.dtype
-        # here would make masked and static modes diverge for bf16 params)
-        return xf + delta
+        received = _exchange(x, name, range(num), pair_lists)
+        with jax.named_scope("gossip/update"):
+            xf = x.astype(jnp.float32)
+            delta = jnp.zeros_like(xf)
+            for j, p in enumerate(received):
+                delta = delta + bits[j].astype(jnp.float32) * (
+                    p.astype(jnp.float32) - xf
+                )
+            # y with x + alpha*(y - x) == x + alpha * sum_j b_j (partner_j - x)
+            # (kept fp32 like the static path: rounding the target to
+            # x.dtype here would make masked and static modes diverge for
+            # bf16 params)
+            return xf + delta
 
     targets = jax.tree.map(partner_target, local)
-    return apply_gossip(local, targets, float(alpha), mesh=info.mesh,
-                        leaf_specs=leaf_specs, impl=impl)
+    with jax.named_scope("gossip/update"):
+        return apply_gossip(local, targets, float(alpha), mesh=info.mesh,
+                            leaf_specs=leaf_specs, impl=impl)
 
 
 # ---------------------------------------------------------------------------

@@ -35,15 +35,18 @@ scan compile times — unrolling via ``scan_layers=False`` is no longer
 the answer. ``--no-stream-scan`` restores the stack-at-once gather for
 A/B comparison.
 
-``--trace DIR`` measures the run instead of only simulating it
-(``repro.telemetry``): sequential modes execute through the *phased*
-step builders (separately fenced executables per runtime phase), each
-matching's exchange is probed as its own fenced ppermute, every step
-prints a measured metrics line (step ms, comm ms, comm/compute overlap
-ratio, modeled bytes), and on exit DIR receives ``events.jsonl``,
-``metrics.jsonl``, and a Perfetto-loadable ``trace.json``. Fencing
-costs dispatch overlap, so traced step times are an upper bound — see
-``docs/observability.md``.
+``--trace DIR`` records a profiler trace of the run as it is: the same
+fused step as an untraced run, under ``jax.profiler`` for
+``TRACE_STEPS`` steps after the first ``TRACE_FROM`` (which compile).
+DIR receives the ``.xplane.pb`` (TensorBoard's profile plugin, or
+``bench/trace.py``) and a ``perfetto_trace.json.gz`` (ui.perfetto.dev).
+Device ops carry the step's named scopes (``fwd_bwd`` with
+``attention``/``ffn``/``ssd``/``head`` inside, ``optimizer``,
+``gossip/matching{j}``, ``gossip/update``); the loop's host work is in
+spans on the same clock (``train/data`` with ``data/sample`` and
+``data/put``, ``train/dispatch``, ``train/wait``, ``train/checkpoint``,
+``train/fault/<kind>``), so each device idle gap has the host span over
+it. See ``docs/observability.md``.
 
 ``--p-drop P`` turns on the fault-injection layer (``repro.faults``):
 a seeded :class:`~repro.faults.FaultSchedule` is declared up front
@@ -70,11 +73,17 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import os
 import time
 
 import numpy as np
+
+# --trace: the profiler records TRACE_STEPS steps from the run's
+# TRACE_FROM-th on; the steps before it compile
+TRACE_FROM = 2
+TRACE_STEPS = 8
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -159,13 +168,14 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--csv", default="")
     ap.add_argument("--non-iid", action="store_true")
     ap.add_argument("--trace", default="", metavar="DIR",
-                    help="measure the run: device-synchronized per-phase "
-                         "timers + per-matching comm probes, a per-step "
-                         "metrics line, and on exit a JSONL event log "
-                         "(events.jsonl) plus a Chrome trace (trace.json, "
-                         "loads in chrome://tracing / Perfetto) in DIR. "
-                         "Adds fencing overhead — leave off for "
-                         "throughput runs (docs/observability.md)")
+                    help=f"write a profiler trace of {TRACE_STEPS} "
+                         f"steps, after the first {TRACE_FROM}, to DIR: "
+                         "the untraced run's step with "
+                         "its named scopes on the device and the loop's "
+                         "train/* and data/* spans on the host "
+                         "(.xplane.pb for TensorBoard, "
+                         "perfetto_trace.json.gz for ui.perfetto.dev; "
+                         "docs/observability.md)")
     return ap
 
 
@@ -173,9 +183,10 @@ def main(argv=None):
     """Run the driver on ``argv`` (default: ``sys.argv[1:]``).
 
     Returns the run's final state for in-process callers
-    (``chip_smoke.py``): ``params`` (node-stacked), ``rows`` (the logged
-    steps), ``plan``/``schedule`` (``None`` for a one-node run),
-    ``bits`` (the last step's activation row) and ``spec``."""
+    (``chip_smoke.py``): ``params`` and ``opt_state`` (node-stacked),
+    ``rows`` (the logged steps), ``plan``/``schedule`` (``None`` for a
+    one-node run), ``bits`` (the last step's activation row), ``spec``
+    and ``steps`` (the step executables the run built)."""
     args = build_parser().parse_args(argv)
 
     if args.nodes < 1:
@@ -191,6 +202,9 @@ def main(argv=None):
                          "--shard must be 1")
     if args.shard < 1:
         raise SystemExit(f"--shard must be >= 1, got {args.shard}")
+    if args.trace and args.steps <= TRACE_FROM:
+        raise SystemExit(f"--trace records the steps after the first "
+                         f"{TRACE_FROM}; --steps must be more")
     # "sequential" and "masked" are the same execution (every matching
     # exchanged in-step, deltas scaled by the schedule bits); both step
     # builders accept either spelling
@@ -407,28 +421,6 @@ def main(argv=None):
         gossip_mode = (
             "none" if args.mode == "local" else args.gossip_mode
         )
-        # --- telemetry (--trace DIR) -----------------------------------
-        # A disabled StepTimer's spans are shared no-ops (identity
-        # fence), so the untraced loop runs the byte-identical program.
-        from repro.telemetry import StepTimer, TraceRecorder
-
-        traced = bool(args.trace)
-        recorder = None
-        if traced:
-            recorder = TraceRecorder(meta=dict(
-                arch=args.arch, preset=args.preset, graph=args.graph,
-                nodes=args.nodes, shard=args.shard, mode=args.mode,
-                gossip_mode=gossip_mode, budget=args.budget,
-                steps=args.steps, batch_per_node=args.batch_per_node,
-                seq=args.seq, p_drop=args.p_drop,
-                fault_seed=args.fault_seed,
-            ))
-        timer = StepTimer(recorder)
-        # Phased executors (per-phase fenced timing) for the sequential
-        # modes; overlap keeps the fused step — fencing its phases would
-        # serialize the very overlap being measured — and is timed
-        # whole-step with per-matching comm probes instead.
-        phased = traced and gossip_mode != "overlap"
         gstate = flush = None
         if gossip_mode == "overlap":
             if use_fsdp:
@@ -449,21 +441,9 @@ def main(argv=None):
                 key = tuple(active)
             if key not in step_cache:
                 if use_fsdp:
-                    if phased:
-                        step_cache[key] = fsdp.make_phased_fsdp_train_step(
-                            model, opt, plan, spec, layout, timer=timer,
-                            gossip_mode=gossip_mode, faulted=faulted,
-                        )
-                    else:
-                        step_cache[key] = fsdp.make_fsdp_train_step(
-                            model, opt, plan, spec, layout,
-                            gossip_mode=gossip_mode, faulted=faulted,
-                        )
-                elif phased:
-                    step_cache[key] = dt.make_phased_train_step(
-                        model, opt, plan, spec, timer=timer,
-                        gossip_mode=gossip_mode, active=tuple(active),
-                        faulted=faulted,
+                    step_cache[key] = fsdp.make_fsdp_train_step(
+                        model, opt, plan, spec, layout,
+                        gossip_mode=gossip_mode, faulted=faulted,
                     )
                 else:
                     step_cache[key] = dt.make_train_step(
@@ -487,6 +467,9 @@ def main(argv=None):
                 return fsdp.consensus_distance_sharded(p)
             return dt.consensus_distance(p)
 
+        ckpt_extra = {"shard": args.shard,
+                      "stream_layers": bool(args.stream_layers),
+                      "stream_scan": bool(args.stream_scan)}
         data = DecentralizedBatches(
             cfg, args.nodes, args.batch_per_node, args.seq,
             iid=not args.non_iid, seed=args.seed,
@@ -498,152 +481,123 @@ def main(argv=None):
         for _ in range(start_step):
             next(it)
 
-        # comm probes: each matching's exchange measured as its own
-        # fenced executable (once, up front; "comm" lane in the trace),
-        # with the modeled per-matching bytes from analysis.bytes_model
-        matching_ms = {}
-        per_matching_bytes = 0
-        if traced and plan is not None:
-            from repro.analysis import bytes_model
-            from repro.telemetry import probes as tprobes
-
-            if use_fsdp:
-                elems = int(layout.plan.total_elements)
-                per_matching_bytes = int(bytes_model.bucket_plan_bytes(
-                    layout.plan, 1)["per_matching_comm_bytes"])
-            else:
-                abs_local = jax.eval_shape(
-                    lambda: model.init(jax.random.key(0))
-                )
-                elems = int(sum(
-                    np.prod(l.shape) for l in jax.tree.leaves(abs_local)
-                ))
-                per_matching_bytes = bytes_model.tree_storage_bytes(abs_local)
-            probe_rows = tprobes.measure_matchings(
-                plan, spec, per_node_elements=elems, timer=timer, iters=3,
-            )
-            matching_ms = {r["matching"]: r["mean_ms"] for r in probe_rows}
-            print("trace: per-matching comm probes "
-                  + " ".join(f"m{r['matching']}={r['mean_ms']:.2f}ms"
-                             for r in probe_rows))
-
         rows = []
-        trace_rows = []
         sim_time = 0.0
         t0 = time.time()
         num_matchings = 0 if plan is None else plan.num_matchings
         bits = jnp.zeros((num_matchings,), jnp.float32)
-        for k in range(start_step, args.steps):
-            batch = next(it)
-            active = () if schedule is None else schedule.active_indices(k)
-            comm_units = 0.0 if schedule is None else schedule.comm_units(k)
-            if faulted:
-                # per-node effective rows: activation bit x link-survival
-                # gate, symmetric across every matching edge (a dropped
-                # exchange zeroes the delta at BOTH endpoints)
-                bits = jnp.asarray(
-                    fault_sched.node_bits(schedule.activations[k], k)
-                )
-            elif schedule is not None:
-                bits = jnp.asarray(
-                    schedule.activations[k].astype(np.float32)
-                )
-            stepf = get_step(active)
-            t0s = time.perf_counter()
-            with timer.phase("step", cat="step", step=k) as sp:
-                if gossip_mode == "overlap":
-                    params, opt_state, gstate, losses, metrics = stepf(
-                        params, opt_state, gstate, batch, bits
-                    )
-                    # delayed gossip hides behind compute: the step costs
-                    # the slower of the two, not their sum
-                    sim_time += max(comm_units, 1.0)
-                elif phased:
-                    params, opt_state, losses, metrics = stepf(
-                        params, opt_state, batch, bits, step=k
-                    )
-                    sim_time += comm_units + 1.0
-                else:
-                    params, opt_state, losses, metrics = stepf(
-                        params, opt_state, batch, bits
-                    )
-                    # paper's delay model: one unit per activated matching
-                    sim_time += comm_units + 1.0   # +1 compute
-                sp.fence((params, losses))
-            if fault_sched is not None:
-                # stragglers stretch the simulated clock: the paper's
-                # delay model is synchronous, so the step costs the
-                # slowest node's extra units
-                delay = fault_sched.max_delay(k)
-                sim_time += delay
-                if traced:
-                    dropped = fault_sched.dropped_links(
-                        schedule.activations[k], k
-                    )
-                    if dropped:
-                        tprobes.fault_event(
-                            recorder, step=k, kind="link_drop",
-                            dropped_exchanges=dropped,
+        # --trace: one profiler window over the steps [trace_from,
+        # trace_to); a span costs next to nothing when no trace runs
+        span = jax.profiler.TraceAnnotation
+        trace_from = start_step + TRACE_FROM if args.trace else -1
+        trace_to = min(trace_from + TRACE_STEPS, args.steps)
+        # no Python-call tracer: it would time every numpy call of the
+        # host's sampler and slow the loop it records
+        profile_options = jax.profiler.ProfileOptions()
+        profile_options.python_tracer_level = 0
+        with contextlib.ExitStack() as window:
+            for k in range(start_step, args.steps):
+                if k == trace_from:
+                    window.enter_context(jax.profiler.trace(
+                        args.trace, create_perfetto_trace=True,
+                        profiler_options=profile_options))
+                with jax.profiler.StepTraceAnnotation("train", step_num=k):
+                    with span("train/data"):
+                        batch = next(it)
+                    active = (() if schedule is None
+                              else schedule.active_indices(k))
+                    comm_units = (0.0 if schedule is None
+                                  else schedule.comm_units(k))
+                    if faulted:
+                        # per-node effective rows: activation bit x
+                        # link-survival gate, symmetric across every
+                        # matching edge (a dropped exchange zeroes the
+                        # delta at BOTH endpoints)
+                        bits = jnp.asarray(fault_sched.node_bits(
+                            schedule.activations[k], k))
+                    elif schedule is not None:
+                        bits = jnp.asarray(
+                            schedule.activations[k].astype(np.float32))
+                    stepf = get_step(active)
+                    with span("train/dispatch"):
+                        if gossip_mode == "overlap":
+                            params, opt_state, gstate, losses, metrics = (
+                                stepf(params, opt_state, gstate, batch,
+                                      bits))
+                            # delayed gossip hides behind compute: the
+                            # step costs the slower of the two, not
+                            # their sum
+                            sim_time += max(comm_units, 1.0)
+                        else:
+                            params, opt_state, losses, metrics = stepf(
+                                params, opt_state, batch, bits)
+                            # paper's delay model: one unit per
+                            # activated matching, +1 compute
+                            sim_time += comm_units + 1.0
+                    if fault_sched is not None:
+                        # stragglers stretch the simulated clock: the
+                        # paper's delay model is synchronous, so the
+                        # step costs the slowest node's extra units
+                        delay = fault_sched.max_delay(k)
+                        sim_time += delay
+                        if fault_sched.dropped_links(
+                                schedule.activations[k], k):
+                            with span("train/fault/link_drop"):
+                                pass
+                        if delay:
+                            with span("train/fault/straggler"):
+                                pass
+                    if k % 10 == 0 or k == args.steps - 1:
+                        with span("train/wait"):
+                            loss_mean = float(jnp.mean(losses))
+                            cons = float(consensus(params))
+                        rows.append(
+                            dict(step=k, loss=loss_mean, consensus=cons,
+                                 sim_time=sim_time, comm_units=comm_units,
+                                 wall=time.time() - t0)
                         )
-                    if delay:
-                        tprobes.fault_event(
-                            recorder, step=k, kind="straggler",
-                            delay_units=delay,
+                        print(
+                            f"step {k:4d} loss {loss_mean:.4f} consensus "
+                            f"{cons:.3e} sim_time {sim_time:.0f}u active "
+                            f"{len(active)}/{num_matchings}"
                         )
-            if traced:
-                step_ms = (time.perf_counter() - t0s) * 1e3
-                if phased:
-                    comm_ms = stepf.last_phase_ms.get("gossip", 0.0)
-                    phase_ms = stepf.last_phase_ms
-                else:
-                    comm_ms = sum(matching_ms.get(j, 0.0) for j in active)
-                    phase_ms = None
-                mrec = tprobes.step_metrics(
-                    step=k, step_ms=step_ms, comm_ms=comm_ms,
-                    gossip_mode=gossip_mode,
-                    comm_bytes=per_matching_bytes * len(active),
-                    phase_ms=phase_ms,
-                )
-                trace_rows.append(mrec)
-                print(tprobes.format_metrics_line(mrec))
-            if k % 10 == 0 or k == args.steps - 1:
-                loss_mean = float(jnp.mean(losses))
-                cons = float(consensus(params))
-                rows.append(
-                    dict(step=k, loss=loss_mean, consensus=cons,
-                         sim_time=sim_time, comm_units=comm_units,
-                         wall=time.time() - t0)
-                )
-                print(
-                    f"step {k:4d} loss {loss_mean:.4f} consensus {cons:.3e} "
-                    f"sim_time {sim_time:.0f}u active {len(active)}/{num_matchings}"
-                )
-            if args.ckpt_every and args.ckpt_dir and (k + 1) % args.ckpt_every == 0:
-                # overlap: checkpoints land the in-flight exchange (the
-                # live run keeps it pending — resuming with a fresh zero
-                # GossipState then replays the uninterrupted trajectory)
-                save_params = (
-                    flush(params, gstate) if gossip_mode == "overlap"
-                    else params
-                )
-                # crash-safe history layout: each checkpoint lands in
-                # its own step_XXXXXXXX/ dir (ckpt.json written last as
-                # the completeness marker) — a crash mid-save can never
-                # damage an earlier restorable checkpoint. Transient
-                # filesystem errors retry with bounded backoff.
-                retry_with_backoff(lambda: ckpt_lib.save_run_step(
-                    args.ckpt_dir, eval_params(save_params),
-                    eval_opt_state(opt_state), step=k + 1,
-                    extra={"shard": args.shard,
-                           "stream_layers": bool(args.stream_layers),
-                           "stream_scan": bool(args.stream_scan)},
-                    keep_last=args.keep_last,
-                ))
-            if fault_spec.crash_at_step == k:
-                if traced:
-                    tprobes.fault_event(recorder, step=k, kind="crash")
-                print(f"fault: simulated crash after completing step {k}")
-                raise SimulatedCrash(k)
+                    if (args.ckpt_every and args.ckpt_dir
+                            and (k + 1) % args.ckpt_every == 0):
+                        # overlap: checkpoints land the in-flight
+                        # exchange (the live run keeps it pending —
+                        # resuming with a fresh zero GossipState then
+                        # replays the uninterrupted trajectory)
+                        save_params = (
+                            flush(params, gstate)
+                            if gossip_mode == "overlap" else params
+                        )
+                        # crash-safe history layout: each checkpoint
+                        # lands in its own step_XXXXXXXX/ dir (ckpt.json
+                        # written last as the completeness marker) — a
+                        # crash mid-save can never damage an earlier
+                        # restorable checkpoint. Transient filesystem
+                        # errors retry with bounded backoff.
+                        with span("train/checkpoint"):
+                            retry_with_backoff(
+                                lambda: ckpt_lib.save_run_step(
+                                    args.ckpt_dir, eval_params(save_params),
+                                    eval_opt_state(opt_state), step=k + 1,
+                                    extra=ckpt_extra,
+                                    keep_last=args.keep_last,
+                                ))
+                    if fault_spec.crash_at_step == k:
+                        with span("train/fault/crash"):
+                            pass
+                        print(f"fault: simulated crash after completing "
+                              f"step {k}")
+                        raise SimulatedCrash(k)
+                if k == trace_to - 1 and args.trace:
+                    # the window ends once its last step is done on the
+                    # device
+                    with span("train/wait"):
+                        jax.block_until_ready((params, losses))
+                    window.close()
+                    print(f"trace: steps {trace_from}..{k} in {args.trace}")
 
         if gossip_mode == "overlap":
             # land the exchange still in flight from the last step
@@ -654,10 +608,7 @@ def main(argv=None):
         if args.ckpt_dir:
             retry_with_backoff(lambda: ckpt_lib.save_run_step(
                 args.ckpt_dir, eval_params(params), eval_opt_state(opt_state),
-                step=args.steps, extra={"shard": args.shard,
-                           "stream_layers": bool(args.stream_layers),
-                           "stream_scan": bool(args.stream_scan)},
-                keep_last=args.keep_last,
+                step=args.steps, extra=ckpt_extra, keep_last=args.keep_last,
             ))
         if args.csv:
             os.makedirs(os.path.dirname(args.csv) or ".", exist_ok=True)
@@ -668,20 +619,9 @@ def main(argv=None):
                 w.writeheader()
                 w.writerows(rows)
             print("wrote", args.csv)
-
-        if traced:
-            import json
-
-            jsonl_path, chrome_path = recorder.flush(args.trace)
-            metrics_path = os.path.join(args.trace, "metrics.jsonl")
-            with open(metrics_path, "w") as f:
-                for r in trace_rows:
-                    f.write(json.dumps(r) + "\n")
-            print(f"wrote trace: {jsonl_path} + {chrome_path} "
-                  f"({len(recorder.events())} events, "
-                  f"{recorder.num_dropped} dropped) and {metrics_path}")
-    return dict(params=params, rows=rows, plan=plan, schedule=schedule,
-                bits=bits, spec=spec)
+    return dict(params=params, opt_state=opt_state, rows=rows, plan=plan,
+                schedule=schedule, bits=bits, spec=spec,
+                steps=dict(step_cache))
 
 
 if __name__ == "__main__":

@@ -258,17 +258,19 @@ def mamba_block(
     xh = shard(xh, ("batch", "seq", "ssm_heads", None))
 
     h0 = None if state is None else state["ssm"]
-    if S == 1:
-        y, h_final = ssd_sequential(
-            xh, dt, A, bs, cs, h0=h0, return_final_state=True
-        )
-    else:
-        chunk = min(cfg.ssm_chunk, S)
-        while S % chunk:
-            chunk //= 2
-        y, h_final = ssd_chunked(
-            xh, dt, A, bs, cs, chunk=chunk, h0=h0, return_final_state=True
-        )
+    with jax.named_scope("ssd"):
+        if S == 1:
+            y, h_final = ssd_sequential(
+                xh, dt, A, bs, cs, h0=h0, return_final_state=True
+            )
+        else:
+            chunk = min(cfg.ssm_chunk, S)
+            while S % chunk:
+                chunk //= 2
+            y, h_final = ssd_chunked(
+                xh, dt, A, bs, cs, chunk=chunk, h0=h0,
+                return_final_state=True,
+            )
     y = y + xh * p["D"].astype(jnp.float32)[None, None, :, None].astype(y.dtype)
     y = y.reshape(Bsz, S, di)
     # gated RMSNorm (mamba2): norm(y * silu(z)); fp32 statistics only

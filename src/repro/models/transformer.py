@@ -379,11 +379,12 @@ class Model:
             )
         else:
             window = cfg.sliding_window if seg.kind == "local" else 0
-            y, new_cache = attention_block(
-                p["mixer"], h, cfg,
-                positions=positions, causal=True, window=window,
-                cache=cache, cache_spec=cache_spec,
-            )
+            with jax.named_scope("attention"):
+                y, new_cache = attention_block(
+                    p["mixer"], h, cfg,
+                    positions=positions, causal=True, window=window,
+                    cache=cache, cache_spec=cache_spec,
+                )
         x = x + y
         if cross_kv is not None:
             h = apply_norm(p["norm_cross"], x, cfg.norm)
@@ -394,14 +395,15 @@ class Model:
         if _has_ffn(cfg, seg):
             x = shard(x, ("batch", "seq_res", "embed"))
             h = apply_norm(p["norm2"], x, cfg.norm)
-            if seg.is_moe:
-                y, moe_aux = moe_block(
-                    p["ffn"], h, cfg,
-                    impl="einsum" if cfg.moe_num_experts <= 8 else "ragged",
-                )
-                aux = {k: aux[k] + moe_aux[k] for k in aux}
-            else:
-                y = ffn_block(p["ffn"], h, cfg)
+            with jax.named_scope("ffn"):
+                if seg.is_moe:
+                    y, moe_aux = moe_block(
+                        p["ffn"], h, cfg,
+                        impl="einsum" if cfg.moe_num_experts <= 8 else "ragged",
+                    )
+                    aux = {k: aux[k] + moe_aux[k] for k in aux}
+                else:
+                    y = ffn_block(p["ffn"], h, cfg)
             x = x + y
         return x, new_cache, aux
 
@@ -564,10 +566,11 @@ class Model:
                 cross_kvs=cross_kvs, decode=False,
             )
             aux_total = {k: aux_total[k] + aux[k] for k in aux_total}
-        x = apply_norm(params["final_norm"], x, cfg.norm)
-        if prefix_len:
-            x = x[:, prefix_len:, :]
-        logits = self._unembed(params, x)
+        with jax.named_scope("head"):
+            x = apply_norm(params["final_norm"], x, cfg.norm)
+            if prefix_len:
+                x = x[:, prefix_len:, :]
+            logits = self._unembed(params, x)
         return logits, aux_total
 
     def _unembed(self, params, x):
@@ -593,7 +596,9 @@ class Model:
         """ce + aux-regularizer objective and its metrics — the ONE
         definition of the training objective; the replicated ``loss``
         and the streamed head stage must optimize the same thing."""
-        ce = softmax_cross_entropy(logits, batch["labels"], batch.get("mask"))
+        with jax.named_scope("head"):
+            ce = softmax_cross_entropy(
+                logits, batch["labels"], batch.get("mask"))
         total = ce + 1e-2 * aux["load_balance"] + 1e-3 * aux["router_z"]
         return total, {"ce": ce, **aux}
 
@@ -799,10 +804,11 @@ class Model:
             view: Dict[str, Any] = {}
             for sub in groups:
                 view.update(sub)
-            x = apply_norm(view["final_norm"], carry["x"], cfg.norm)
-            if prefix_len:
-                x = x[:, prefix_len:, :]
-            logits = self._unembed(view, x)
+            with jax.named_scope("head"):
+                x = apply_norm(view["final_norm"], carry["x"], cfg.norm)
+                if prefix_len:
+                    x = x[:, prefix_len:, :]
+                logits = self._unembed(view, x)
             total, metrics = self._combine_loss(
                 logits, carry["batch"], carry["aux"]
             )

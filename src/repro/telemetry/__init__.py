@@ -1,33 +1,26 @@
-"""Measured wall-clock telemetry for the decentralized runtime.
+"""Host-clock telemetry for the serve launcher and the comm-time bench.
 
-MATCHA's headline claim is an *error-runtime* win — less wall-clock
-time to the same loss — but the rest of this repo charges time with the
-paper's linear delay model (``comm_units + 1`` sequential,
-``max(comm_units, 1)`` overlapped). This package is the measurement
-side: low-overhead host timers and an event log that turn the simulated
-trade-off curves into measured ones.
-
-Three modules:
+The train launcher's ``--trace`` uses the profiler instead
+(``jax.profiler``: device ops with their named scopes and the loop's
+host spans on one clock, ``docs/observability.md``). What remains here
+is the host-clock side that ``launch/serve.py`` and
+``benchmarks/bench_comm_time.py`` still use:
 
 * :mod:`repro.telemetry.trace` — the event model. ``TraceEvent`` (one
   completed span, microsecond units), ``TraceRecorder`` (bounded ring
   buffer), JSONL event-log read/write and a lossless Chrome-trace
   (``chrome://tracing`` / Perfetto) export. Schema documented in
   ``docs/observability.md``.
-* :mod:`repro.telemetry.timers` — ``StepTimer``: phase spans with
+* :mod:`repro.telemetry.timers` — ``StepTimer``: spans with
   ``jax.block_until_ready`` fencing at the boundaries when tracing is
   on, and a zero-cost no-op path when off (``timed_step`` returns the
   wrapped callable *unchanged* — same object — so the traced program
   cannot differ).
-* :mod:`repro.telemetry.probes` — measured communication: per-matching
-  ppermute probes (each matching's exchange timed as its own fenced
-  executable) and the per-step metrics record (measured step/comm ms,
-  comm/compute overlap ratio, bytes from ``repro.analysis.bytes_model``).
+* :mod:`repro.telemetry.probes` — per-matching ppermute probes, each
+  matching's exchange timed as its own fenced executable.
 
-Nothing here imports ``repro.dist`` at module scope (the dist modules
-own the phase *hooks*; probes import them lazily), so enabling
-telemetry never changes what the training step traces — the property
-``tests/test_telemetry.py`` locks down via ``repro.analysis.traversal``.
+Nothing here imports ``repro.dist`` at module scope (probes import it
+lazily), so importing telemetry never changes what a step traces.
 """
 from __future__ import annotations
 

@@ -6,8 +6,9 @@ measures only the enqueue unless the result is fenced; a phase span
 therefore ends with ``span.fence(outputs)`` — ``jax.block_until_ready``
 on the phase's outputs — so ``dur_us`` covers the device work the phase
 launched. That fence is also the overhead: fencing serializes dispatch
-at every phase boundary, so per-phase numbers are only collected when
-tracing is on (see ``docs/observability.md`` for the caveats).
+at every span boundary, so spans are only recorded when tracing is on.
+The serve launcher uses it; the train launcher traces with the profiler
+(``docs/observability.md``).
 
 Off path: a disabled timer's ``phase(...)`` returns a shared no-op span
 whose ``fence`` is identity, and :func:`timed_step` returns the wrapped
@@ -18,9 +19,7 @@ construction no change to any traced jaxpr
 ``repro.analysis.traversal``).
 
 Phase names are free-form; the canonical ones the runtime emits are in
-``PHASES``. Spans nest (``depth`` is recorded per event): the train
-drivers wrap the whole step in a ``"step"`` span and the phased
-executors emit child spans per runtime phase.
+``PHASES``. Spans nest (``depth`` is recorded per event).
 """
 from __future__ import annotations
 
@@ -29,23 +28,13 @@ from typing import Any, Callable, Optional, Tuple
 
 from repro.telemetry.trace import TraceEvent, TraceRecorder
 
-# Canonical phase names emitted by the runtime (docs/observability.md
-# documents each; free-form names are also fine):
-#   step            one whole train step (fenced outputs)
-#   gather          fsdp all-gather of the bucket shards ("shard" axis)
-#   fwd_bwd         forward + backward on the node's batch slice
-#   reduce_scatter  grad psum_scatter over the shard axis
-#   optimizer       elementwise update on the resident state
-#   gossip          the per-step matching exchange (sequential modes)
+# Canonical span names the callers emit (docs/observability.md;
+# free-form names are also fine):
+#   step               one whole fenced step (benchmarks/bench_comm_time)
 #   gossip/matchingJ   one matching's ppermute (comm probes)
 #   prefill / decode   serve-side spans
 PHASES: Tuple[str, ...] = (
     "step",
-    "gather",
-    "fwd_bwd",
-    "reduce_scatter",
-    "optimizer",
-    "gossip",
     "prefill",
     "decode",
 )

@@ -48,8 +48,7 @@ class TraceEvent:
                 ``"gossip/matching3"``).
     ``cat``     coarse category used for aggregation and Perfetto
                 filtering: ``"step"`` | ``"phase"`` | ``"comm"`` |
-                ``"serve"`` | ``"probe"`` | ``"fault"`` (injected
-                fault instants — ``repro.faults``).
+                ``"serve"`` | ``"probe"``.
     ``ts_us``   span start, microseconds since the recorder epoch.
     ``dur_us``  span length, microseconds (>= 0).
     ``step``    training/decoding step index, -1 when not step-scoped.

@@ -1,6 +1,6 @@
 """repro.telemetry: schema round-trip, timer semantics, the
-tracing-off no-change guarantee, phased-vs-fused parity, and the
---trace driver smoke.
+tracing-off no-change guarantee, and the train launcher's ``--trace``
+(a profiler trace of the untraced run's own step).
 
 The pure trace/timer tests run in-process (no jax device work). The
 runtime tests follow the repo's subprocess convention (XLA host device
@@ -176,22 +176,6 @@ def test_enabled_timer_requires_recorder():
         StepTimer(None, enabled=True)
 
 
-def test_step_metrics_fields():
-    from repro.telemetry.probes import format_metrics_line, step_metrics
-
-    m = step_metrics(step=3, step_ms=50.0, comm_ms=10.0,
-                     gossip_mode="masked", comm_bytes=4096,
-                     phase_ms={"fwd_bwd": 35.0, "gossip": 10.0})
-    assert m["comm_fraction"] == 0.2
-    assert m["overlap_ratio"] == 0.0     # only overlap mode reports it
-    assert m["fwd_bwd_ms"] == 35.0
-    mo = step_metrics(step=0, step_ms=50.0, comm_ms=30.0,
-                      gossip_mode="overlap")
-    assert mo["overlap_ratio"] == 0.6
-    line = format_metrics_line(m)
-    assert "trace step" in line and "comm" in line and "fwd_bwd" in line
-
-
 # ---------------------------------------------------------------------------
 # tracing-off: no jaxpr / collective changes
 # ---------------------------------------------------------------------------
@@ -250,117 +234,82 @@ def test_named_scope_and_fused_step_unchanged():
 
 
 # ---------------------------------------------------------------------------
-# phased executors == fused step
+# launcher smoke: --trace produces a loadable trace
 # ---------------------------------------------------------------------------
-def test_phased_step_matches_fused():
-    """make_phased_train_step (separately fenced executables, used by
-    --trace) must reproduce the fused masked step's trajectory and
-    populate last_phase_ms for every phase."""
-    run_sub("""
-        import jax, jax.numpy as jnp
-        import numpy as np
+TRACE_RUN = """
+    import sys
+    import jax, jax.numpy as jnp
 
-        from repro.configs.registry import get_smoke_config
-        from repro.core import paper_figure1_graph, plan_matcha
-        from repro.data.pipeline import DecentralizedBatches
-        from repro.dist import decen_train as dt
-        from repro.dist import sharding as shd
-        from repro.launch.mesh import make_test_mesh
-        from repro.models.transformer import Model
-        from repro.optim.optimizers import sgd
-        from repro.telemetry import StepTimer, TraceRecorder
+    from repro.launch import train
 
-        cfg = get_smoke_config("internlm2_1_8b")
-        model = Model(cfg)
-        mesh = make_test_mesh(nodes=8, model=1)
-        spec = dt.make_spec(mesh, cfg)
-        plan = plan_matcha(paper_figure1_graph(), 0.5, budget_steps=400)
-        sched = plan.schedule(3, seed=1)
-        opt = sgd(0.3, momentum=0.9)
-
-        def init():
-            p = dt.init_stacked_params(model, spec, seed=0)
-            o = dt.init_stacked_opt_state(opt, model, spec)
-            ps = dt.stacked_param_shardings(model, spec)
-            p = jax.device_put(p, shd.named_shardings(ps, mesh))
-            return p, o
-
-        rec = TraceRecorder()
-        timer = StepTimer(rec)
-        with jax.set_mesh(mesh):
-            fused = dt.make_train_step(model, opt, plan, spec,
-                                       gossip_mode="masked")
-            phased = dt.make_phased_train_step(model, opt, plan, spec,
-                                               timer=timer,
-                                               gossip_mode="masked")
-            pf, of = init()
-            pp, op = init()
-            data = DecentralizedBatches(cfg, 8, 2, 32, seed=0)
-            it = iter(data)
-            for k in range(3):
-                bits = jnp.asarray(sched.activations[k].astype(np.float32))
-                batch = next(it)
-                pf, of, lf, _ = fused(pf, of, batch, bits)
-                pp, op, lp, _ = phased(pp, op, batch, bits, step=k)
-                np.testing.assert_allclose(
-                    np.asarray(lf), np.asarray(lp), rtol=2e-5)
-        for leaf_f, leaf_p in zip(jax.tree.leaves(pf), jax.tree.leaves(pp)):
-            np.testing.assert_allclose(
-                np.asarray(leaf_f), np.asarray(leaf_p), rtol=2e-4, atol=1e-5)
-        assert set(phased.last_phase_ms) == {"fwd_bwd", "optimizer", "gossip"}
-        assert all(v >= 0 for v in phased.last_phase_ms.values())
-        names = {e.name for e in rec.events()}
-        assert {"fwd_bwd", "optimizer", "gossip"} <= names
-        # overlap mode must refuse phased fencing (it would serialize
-        # the overlap being measured)
-        try:
-            dt.make_phased_train_step(model, opt, plan, spec,
-                                      timer=timer, gossip_mode="overlap")
-            raise AssertionError("phased overlap did not raise")
-        except ValueError:
-            pass
-        print("OK")
-    """)
+    trace_dir, hlo_out = sys.argv[1], sys.argv[2]
+    argv = ["--preset", "tiny", "--nodes", "4", "--graph", "ring",
+            "--steps", "4", "--batch-per-node", "2", "--seq", "32"]
+    traced = train.main(argv + ["--trace", trace_dir])
+    plain = train.main(argv)
+    assert set(traced["steps"]) == set(plain["steps"]) == {"masked"}
+    batch = {k: jax.ShapeDtypeStruct((4, 2, 32), jnp.int32)
+             for k in ("tokens", "labels")}
+    with jax.set_mesh(traced["spec"].mesh):
+        lowered = [out["steps"]["masked"].lower(
+            out["params"], out["opt_state"], batch, out["bits"])
+            for out in (traced, plain)]
+        assert lowered[0].as_text() == lowered[1].as_text()
+        with open(hlo_out, "w") as f:
+            f.write(lowered[0].compile().as_text())
+    print("OK")
+"""
 
 
-# ---------------------------------------------------------------------------
-# driver smoke: --trace produces a loadable trace
-# ---------------------------------------------------------------------------
 def test_train_trace_smoke(tmp_path):
-    """--trace on the tiny preset must emit events.jsonl + metrics.jsonl
-    + a Chrome trace that loads and round-trips."""
+    """--trace writes a profiler trace of steps 2 and 3: the host plane
+    holds each step and the loop's data, dispatch and wait spans, the
+    ops the CPU ran carry the step's named scopes, and the traced run's
+    step is the untraced run's (the same lowered program)."""
+    import glob
+
     out_dir = str(tmp_path / "trace")
+    hlo = str(tmp_path / "step.hlo")
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     res = subprocess.run(
-        [sys.executable, "-m", "repro.launch.train", "--preset", "tiny",
-         "--nodes", "8", "--steps", "4", "--batch-per-node", "2",
-         "--seq", "32", "--trace", out_dir],
+        [sys.executable, "-c", textwrap.dedent(TRACE_RUN), out_dir, hlo],
         capture_output=True, text=True, timeout=900, env=env, cwd=REPO,
     )
     assert res.returncode == 0, f"stderr:\n{res.stderr[-4000:]}"
+    assert "trace: steps 2..3" in res.stdout and "OK" in res.stdout
 
-    sys.path.insert(0, os.path.join(REPO, "src"))
-    from repro.telemetry import read_jsonl
-    from repro.telemetry.trace import (
-        CHROME_TRACE, EVENTS_JSONL, SCHEMA, read_chrome_trace,
-    )
+    from jax.profiler import ProfileData
 
-    header, events = read_jsonl(os.path.join(out_dir, EVENTS_JSONL))
-    assert header["schema"] == SCHEMA
-    assert header["meta"]["preset"] == "tiny"
-    assert events, "no events recorded"
-    names = {e.name for e in events}
-    assert "step" in names and "fwd_bwd" in names
-    assert any(n.startswith("gossip/matching") for n in names)
-    chrome = read_chrome_trace(os.path.join(out_dir, CHROME_TRACE))
-    assert chrome == events              # lossless export
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from bench.trace import in_scope, scope_map
 
-    with open(os.path.join(out_dir, "metrics.jsonl")) as f:
-        metrics = [json.loads(line) for line in f]
-    assert len(metrics) == 4
-    for m in metrics:
-        assert m["step_ms"] > 0 and m["comm_ms"] >= 0
-        assert m["comm_fraction"] >= 0.0 and m["comm_bytes"] > 0
-    assert "wrote trace:" in res.stdout
+    files = glob.glob(os.path.join(out_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1
+    assert glob.glob(os.path.join(out_dir, "**", "perfetto_trace.json.gz"),
+                     recursive=True)
+    data = ProfileData.from_file(files[0])
+    # on the CPU the host plane holds both the spans and the ops, each
+    # op named as its HLO instruction
+    names, steps = set(), []
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                names.add(ev.name)
+                if ev.name == "train":
+                    steps.append(dict(ev.stats)["step_num"])
+    assert {"train/data", "data/sample", "data/put", "train/dispatch",
+            "train/wait"} <= names
+    assert sorted(steps) == [2, 3]
+    with open(hlo) as f:
+        smap = scope_map(f.read())
+    scopes = [smap[n] for n in names if n in smap]
+    for want in ("attention", "ffn", "head"):
+        assert any(in_scope(s, "fwd_bwd") and in_scope(s, want)
+                   for s in scopes), want
+    assert any(in_scope(s, "gossip/update") for s in scopes)
