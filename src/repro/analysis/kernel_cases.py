@@ -90,6 +90,8 @@ def _attention_cases(arch, preset, cfg):
 
 
 def _ssd_cases(arch, preset, cfg):
+    """The SSD kernel pair: the forward alone, and forward and backward
+    (the gradient traces both kernels)."""
     d_inner = cfg.ssm_expand * cfg.d_model
     P = cfg.ssm_head_dim or 64
     H = cfg.ssm_num_heads or max(1, d_inner // P)
@@ -97,20 +99,25 @@ def _ssd_cases(arch, preset, cfg):
     chunk = cfg.ssm_chunk
     S = 2 * chunk
     dt = _dtype(cfg)
-    fn = functools.partial(ops.ssd, chunk=chunk, impl="pallas")
-    return [KernelCase(
-        label=f"{arch}/{preset}/ssm_scan/aligned",
-        fn=fn,
-        args=(
-            jax.ShapeDtypeStruct((BATCH, S, H, P), dt),
-            jax.ShapeDtypeStruct((BATCH, S, H), dt),
-            jax.ShapeDtypeStruct((H,), jnp.float32),
-            jax.ShapeDtypeStruct((BATCH, S, N), dt),
-            jax.ShapeDtypeStruct((BATCH, S, N), dt),
-        ),
-        contract=_ss.KERNEL_CONTRACT,
-        guards={},
-    )]
+    fwd = functools.partial(ops.ssd, chunk=chunk, impl="pallas")
+
+    def fwd_bwd(*args):
+        return jax.grad(
+            lambda *a: jnp.sum(fwd(*a)[0].astype(jnp.float32)),
+            argnums=(0, 1, 2, 3, 4))(*args)
+
+    args = (
+        jax.ShapeDtypeStruct((BATCH, S, H, P), dt),
+        jax.ShapeDtypeStruct((BATCH, S, H), jnp.float32),
+        jax.ShapeDtypeStruct((H,), jnp.float32),
+        jax.ShapeDtypeStruct((BATCH, S, N), dt),
+        jax.ShapeDtypeStruct((BATCH, S, N), dt),
+    )
+    return [
+        KernelCase(label=f"{arch}/{preset}/ssm_scan/{tag}", fn=fn,
+                   args=args, contract=_ss.KERNEL_CONTRACT, guards={})
+        for tag, fn in (("aligned", fwd), ("aligned_grad", fwd_bwd))
+    ]
 
 
 def _gmm_cases(arch, preset, cfg):

@@ -90,18 +90,23 @@ def attention(
 # ---------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("chunk", "impl"))
 def ssd(
-    x, dt, A, B_mat, C_mat, *, chunk: int = 128, impl: str = "auto"
+    x, dt, A, B_mat, C_mat, *, chunk: int = 128, h0=None,
+    impl: str = "auto",
 ):
+    """``ssd_chunked(..., return_final_state=True)``: (y, final state).
+    The chunk is halved until it divides the sequence."""
+    from repro.models.ssm import ssd_chunked
+
     mode = resolve_mode(impl)
-    if mode == "xla":
-        return _ref.ssm_scan_ref(x, dt, A, B_mat, C_mat)
     S = x.shape[1]
     c = min(chunk, S)
     while S % c:
         c //= 2
-    return _ss.ssm_scan(
-        x, dt, A, B_mat, C_mat, chunk=c, interpret=mode == "interpret"
-    )
+    if mode == "xla":
+        return ssd_chunked(x, dt, A, B_mat, C_mat, chunk=c, h0=h0,
+                           return_final_state=True)
+    return _ss.ssd(x, dt, A, B_mat, C_mat, chunk=c, h0=h0,
+                   interpret=mode == "interpret")
 
 
 # ---------------------------------------------------------------------------

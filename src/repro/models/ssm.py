@@ -10,8 +10,10 @@ Three execution paths:
     for decode (one step) and in ref tests;
   * chunked SSD (this file): intra-chunk attention-like masked matmul +
     inter-chunk state scan. O(S Q) instead of O(S^2); the train/prefill
-    path and what the Pallas ``ssm_scan`` kernel implements on TPU;
-  * the Pallas kernel itself (repro.kernels.ssm_scan), swap-in on TPU.
+    path off the chip and for shapes the kernel does not tile;
+  * the Pallas kernel pair (repro.kernels.ssm_scan, forward and
+    backward), the same chunked evaluation with its intermediates in
+    VMEM: ``mamba_block`` takes it on TPU where the shapes tile.
 
 Sharding: heads are tensor-parallel ("heads"); B/C projections are
 per-group (ngroups=1) and replicated; the state (B, H, N, P) shards over
@@ -24,9 +26,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src.mesh import get_concrete_mesh
+from jax.sharding import Mesh, PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import shard
+from repro.kernels import ops, ssm_scan
 from repro.models.layers import apply_dense, declare_dense
 from repro.models.module import ParamBuilder, ones_init, zeros_init
 
@@ -89,7 +94,7 @@ def _conv_init(key, shape, dtype):
 
 
 # ---------------------------------------------------------------------------
-# Chunked SSD core (pure jnp; mirrored by kernels/ssm_scan.py on TPU)
+# Chunked SSD core (pure jnp; kernels/ssm_scan.py evaluates it on TPU)
 # ---------------------------------------------------------------------------
 def ssd_chunked(
     x: jax.Array,        # (B, S, H, P)
@@ -217,6 +222,29 @@ def causal_conv1d(
     return jax.nn.silu(out), new_state
 
 
+def _manual_region(fn):
+    """``fn`` in a region manual over every mesh axis, as a Mosaic
+    kernel needs: the compiler cannot partition one. None where that
+    would split the kernel's operands (an axis left to the compiler
+    spans several devices: tensor parallel) or where no mesh set by
+    ``jax.set_mesh`` names the devices of such axes. As in
+    ``dist.gossip.apply_gossip``, the region takes the run's devices
+    with the abstract mesh's axis types, so the enclosing manual axes
+    stay manual; ``jax.sharding.get_mesh`` refuses to run under ``jit``,
+    so the mesh is read from where ``jax.set_mesh`` keeps it."""
+    here = jax.sharding.get_abstract_mesh()
+    left = set(here.axis_names) - set(here.manual_axes)
+    if not left:
+        return fn
+    mesh = get_concrete_mesh()
+    if mesh.empty or any(here.shape[a] > 1 for a in left):
+        return None
+    mesh = Mesh(mesh.devices, mesh.axis_names, axis_types=here.axis_types)
+    return jax.shard_map(fn, mesh=mesh, in_specs=PartitionSpec(),
+                         out_specs=PartitionSpec(), axis_names=left,
+                         check_vma=False)
+
+
 # ---------------------------------------------------------------------------
 # Full Mamba2 block
 # ---------------------------------------------------------------------------
@@ -267,10 +295,23 @@ def mamba_block(
             chunk = min(cfg.ssm_chunk, S)
             while S % chunk:
                 chunk //= 2
-            y, h_final = ssd_chunked(
-                xh, dt, A, bs, cs, chunk=chunk, h0=h0,
-                return_final_state=True,
-            )
+            mode = ops.resolve_mode("auto")
+
+            def scan(x, dt, A, B_mat, C_mat, h0):
+                return ops.ssd(x, dt, A, B_mat, C_mat, chunk=chunk, h0=h0,
+                               impl=mode)
+
+            kernel = None
+            if mode != "xla" and ssm_scan.tiles(S, H, P, chunk):
+                kernel = _manual_region(scan)
+            if kernel is not None:
+                with jax.named_scope("kernel"):
+                    y, h_final = kernel(xh, dt, A, bs, cs, h0)
+            else:
+                y, h_final = ssd_chunked(
+                    xh, dt, A, bs, cs, chunk=chunk, h0=h0,
+                    return_final_state=True,
+                )
     y = y + xh * p["D"].astype(jnp.float32)[None, None, :, None].astype(y.dtype)
     y = y.reshape(Bsz, S, di)
     # gated RMSNorm (mamba2): norm(y * silu(z)); fp32 statistics only

@@ -81,13 +81,29 @@ def _local_step_hlo(arch: str) -> str:
         return step.lower(params, ostate, batch, bits).compile().as_text()
 
 
+@pytest.fixture
+def kernels_interpreted(monkeypatch):
+    """``auto`` resolves to the interpreted Pallas kernels, as it resolves
+    to the compiled ones on the chip: the model takes the kernel paths
+    the chip takes, on the CPU."""
+    from repro.kernels import ops
+
+    resolve = ops.resolve_mode
+    monkeypatch.setattr(
+        ops, "resolve_mode", lambda impl, off_tpu="xla": resolve(
+            "interpret" if impl == "auto" else impl, off_tpu=off_tpu))
+
+
 @pytest.mark.parametrize("arch,blocks", [
     ("internlm2_1_8b", {"attention", "ffn", "head"}),
     ("mamba2_370m", {"ssd", "head"}),
 ])
-def test_model_scopes_reach_the_compiled_step(arch, blocks):
+def test_model_scopes_reach_the_compiled_step(arch, blocks,
+                                              kernels_interpreted):
     """Each block the model has is under ``fwd_bwd``, in the backward
-    pass too; a block it lacks has no op."""
+    pass too; a block it lacks has no op. The SSD scan's kernel pair
+    runs under ``ssd/kernel``, forward (and its remat recompute) and
+    backward, where ``ssd_ms`` reads it."""
     smap = scope_map(_local_step_hlo(arch))
     counts = _fwd_bwd_blocks(smap)
     assert {b for b, n in counts.items() if n} == blocks, counts
@@ -95,6 +111,12 @@ def test_model_scopes_reach_the_compiled_step(arch, blocks):
         assert any(in_scope(s, "fwd_bwd") and in_scope(s, b)
                    and "transpose" in s for s in smap.values()), b
     assert not any(in_scope(s, "gossip") for s in smap.values())
+    kernel = [s for s in smap.values()
+              if in_scope(s, "fwd_bwd") and in_scope(s, "ssd/kernel")]
+    assert bool(kernel) == ("ssd" in blocks)
+    if kernel:
+        assert any("transpose" in s for s in kernel)
+        assert any("transpose" not in s for s in kernel)
 
 
 RING_HLO = """

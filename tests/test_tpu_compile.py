@@ -13,6 +13,7 @@ steered inside each test so the hot path resolves to the compiled
 kernel as it does on the chip.
 """
 import dataclasses
+import re
 
 import pytest
 
@@ -70,10 +71,12 @@ def _footprint(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-def _compile_step(topo, *, layers, nodes, gossip_mode, model_par=1):
-    """The launcher's replicated train step at internlm2-1.8B widths,
-    batch 4 x 128 per node, ``model_par`` described chips per node."""
-    cfg = dataclasses.replace(get_config("internlm2_1_8b"), num_layers=layers)
+def _compile_step(topo, *, layers, nodes, gossip_mode, model_par=1,
+                  arch="internlm2_1_8b", batch=(4, 128)):
+    """The launcher's replicated train step at ``arch``'s widths (default
+    internlm2-1.8B), ``batch`` sequences x tokens per node,
+    ``model_par`` described chips per node."""
+    cfg = dataclasses.replace(get_config(arch), num_layers=layers)
     model = Model(cfg)
     mesh = make_mesh((nodes, model_par), ("data", "model"),
                      devices=topo.devices[:nodes * model_par])
@@ -95,7 +98,7 @@ def _compile_step(topo, *, layers, nodes, gossip_mode, model_par=1):
     opt_state = placed(jax.eval_shape(
         lambda: dt.init_stacked_opt_state(opt, model, spec)), ospecs)
     batch = {k: jax.ShapeDtypeStruct(
-        (nodes, 4, 128), jnp.int32, sharding=NamedSharding(mesh, P("data")))
+        (nodes, *batch), jnp.int32, sharding=NamedSharding(mesh, P("data")))
         for k in ("tokens", "labels")}
     bits = jax.ShapeDtypeStruct(
         (0 if plan is None else plan.num_matchings,), jnp.float32,
@@ -143,3 +146,22 @@ def test_tensor_parallel_masked_step_gathers_no_params(topo, on_tpu):
         topo, layers=2, nodes=2, gossip_mode="none", model_par=2).as_text()
     assert "tpu_custom_call" in masked
     assert masked.count("all-gather") == local.count("all-gather")
+
+
+def test_mamba2_step_takes_the_ssd_kernel(topo, on_tpu):
+    """The benchmark's mamba2-370m cell: 48 layers, one node, 8 x 2048.
+    The SSD scan lowers to the Pallas kernel pair under ``fwd_bwd`` and
+    ``ssd/kernel`` (where ``ssd_ms`` reads it), and the step's footprint
+    stays under the 11.80 GB of the jnp scan it replaces."""
+    compiled = _compile_step(topo, layers=48, nodes=1, gossip_mode="none",
+                             arch="mamba2_370m", batch=(8, 2048))
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # forward, its remat recompute, backward
+    assert len(calls) == 3, len(calls)
+    for line in calls:
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        parts = [p for p in re.split(r"[/()]", op_name) if p]
+        assert "fwd_bwd" in parts, op_name
+        assert "ssd/kernel" in "/".join(parts), op_name
+    assert _footprint(compiled) < 11.80e9, _footprint(compiled)
